@@ -21,7 +21,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from ..jax_compat import axis_size
 
 from .vector_engine import log2i
 
@@ -64,7 +63,7 @@ def _xor_perm(size: int, d: int):
 def allreduce_hd(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Halving-doubling (latency-optimal) all-reduce: log2(L) full-size
     XOR-partner exchanges - the paper's inter-lane tree verbatim."""
-    size = axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     d = 1
     while d < size:
         x = x + jax.lax.ppermute(x, axis_name, _xor_perm(size, d))
@@ -75,7 +74,7 @@ def allreduce_hd(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
 def reduce_scatter_hd(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Recursive-halving reduce-scatter along leading dim (bandwidth-optimal:
     (L-1)/L of |x| per link).  Shard i of the result is chunk i."""
-    size = axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     assert x.shape[0] % size == 0, f"leading dim {x.shape[0]} % {size} != 0"
     d = size >> 1
@@ -94,7 +93,7 @@ def reduce_scatter_hd(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
 def allgather_hd(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Recursive-doubling all-gather along leading dim (inverse of
     :func:`reduce_scatter_hd`'s placement)."""
-    size = axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     d = 1
     while d < size:
@@ -111,7 +110,7 @@ def allreduce_rs_ag(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Bandwidth-optimal all-reduce = recursive-halving reduce-scatter +
     recursive-doubling all-gather (2*(L-1)/L of |x| per link)."""
     shape = x.shape
-    size = axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     flat = x.reshape(-1)
     pad = (-flat.shape[0]) % size
     if pad:

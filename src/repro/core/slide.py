@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from ..jax_compat import axis_size
 
 from .vector_engine import log2i
 
@@ -89,7 +88,7 @@ def mesh_slide(x: jnp.ndarray, amount: int, axis_name: str) -> jnp.ndarray:
     <= log2(L) ppermute steps, each a fixed-stride neighbor-class hop on the
     ICI torus - the paper's O(L log L) argument transplanted to collectives.
     """
-    size = axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     amount %= size
     for step in decompose_pow2(amount):
         perm = [(i, (i + step) % size) for i in range(size)]
@@ -101,7 +100,7 @@ def mesh_halo_exchange(x: jnp.ndarray, halo: int, axis_name: str, axis: int = 0)
     """Exchange ``halo`` boundary rows with both mesh neighbors (slide-by-one,
     the SLDU's cheapest configuration).  Returns (left_halo, right_halo) from
     the neighboring shards; edges wrap (callers mask if non-periodic)."""
-    size = axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     sl_lo = [slice(None)] * x.ndim
     sl_lo[axis] = slice(0, halo)
     sl_hi = [slice(None)] * x.ndim

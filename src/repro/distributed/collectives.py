@@ -13,7 +13,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from ..jax_compat import axis_size
 
 from ..core.reduction import allreduce_hd, allreduce_rs_ag
 
@@ -62,7 +61,7 @@ def compressed_allreduce(x, axis_name, *, error: jnp.ndarray | None = None,
     scale_max * sum q_i; caught by the error-feedback property test).
     Phase 2 sums the int8 payload in int32.  Link bytes: ~1/4 of fp32 plus
     the 1/BLOCK scale exchange.  Returns (mean-reduced value, new error)."""
-    size = axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     val = x if error is None else x + error
     # shared blockwise scale
     _, scale_local, meta = quantize_int8(val, block=block)
@@ -88,7 +87,7 @@ def grad_sync(grads, axis_name, *, mode: str = "psum", error_state=None):
         return jax.tree_util.tree_map(
             lambda g: jax.lax.pmean(g, axis_name), grads), error_state
     if mode in ("tree_bw", "tree_hd"):
-        size = axis_size(axis_name)
+        size = jax.lax.axis_size(axis_name)
         return jax.tree_util.tree_map(
             lambda g: tree_allreduce(g, axis_name,
                                      bandwidth_optimal=mode == "tree_bw")

@@ -16,7 +16,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -43,7 +42,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     qpos = pl.program_id(2) * bq + jax.lax.broadcasted_iota(
         jnp.int32, (bq, bk), 0) + (sk - sq)       # right-aligned queries
     kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = jnp.ones((bq, bk), jnp.bool_)
+    mask = kpos < sk                              # keys padded to the tile
     if causal:
         mask &= kpos <= qpos
     if window is not None:
@@ -72,18 +71,22 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "bq", "bk", "interpret"))
 def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
                            bq=128, bk=128, interpret=False):
+    """Any sq/sk: a length that is not a multiple of its tile is padded up
+    to one; the kernel masks the padded keys (``kpos < sk``) and the
+    padded query rows are sliced off the output."""
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     g = hq // hkv
     scale = float(scale if scale is not None else 1.0 / np.sqrt(d))
     bq, bk = min(bq, sq), min(bk, sk)
-    assert sq % bq == 0 and sk % bk == 0
-    kv_steps = sk // bk
-    grid = (b, hq, sq // bq, kv_steps)
+    q_pad, k_pad = _pad_to(q, bq), _pad_to(k, bk)
+    v_pad = _pad_to(v, bk)
+    kv_steps = k_pad.shape[2] // bk
+    grid = (b, hq, q_pad.shape[2] // bq, kv_steps)
     kern = functools.partial(_flash_kernel, scale=scale, causal=causal,
                              window=window, bq=bq, bk=bk, sq=sq, sk=sk,
                              kv_steps=kv_steps)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -92,17 +95,27 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, iq, ik, g_=g: (b_, h // g_, ik, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h, iq, ik: (b_, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_pad.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(q_pad, k_pad, v_pad)
+    return out[:, :, :sq]
+
+
+def _pad_to(x, tile):
+    """Right-pad axis 2 (the sequence axis) of ``x`` to a multiple of
+    ``tile`` with zeros."""
+    pad = -x.shape[2] % tile
+    if pad == 0:
+        return x
+    return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
 
 
 # ---------------------------------------------------------------------------
@@ -123,11 +136,13 @@ def _mask_block(qpos, kpos, causal, window):
     return mask
 
 
-def _mask_block_f(qpos, kpos, causal, window_f):
+def _mask_block_f(qpos, kpos, causal, window_f, sk_valid):
     """Float-window variant: window rides as an f32 operand so traced
     per-layer windows (gemma3's 5:1 pattern under scan) work through the
-    custom-VJP.  1e30 disables the window."""
-    mask = jnp.ones((qpos.shape[0], kpos.shape[0]), jnp.bool_)
+    custom-VJP.  1e30 disables the window.  Keys at or past ``sk_valid``
+    are tile padding."""
+    mask = jnp.broadcast_to(kpos[None, :] < sk_valid,
+                            (qpos.shape[0], kpos.shape[0]))
     if causal:
         mask &= kpos[None, :] <= qpos[:, None]
     mask &= kpos[None, :].astype(jnp.float32) \
@@ -135,15 +150,18 @@ def _mask_block_f(qpos, kpos, causal, window_f):
     return mask
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q5, kc, vc, window_f, scale, causal, q_offset, kv_chunk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q5, kc, vc, window_f, scale, causal, q_offset, kv_chunk,
+           sk_valid):
     out, _ = _flash_fwd_impl(q5, kc, vc, window_f, scale, causal, q_offset,
-                             kv_chunk)
+                             kv_chunk, sk_valid)
     return out
 
 
-def _flash_fwd_impl(q5, kc, vc, window_f, scale, causal, q_offset, kv_chunk):
-    """q5: (B, Hkv, G, Sq, D) fp32; kc/vc: (B, Hkv, Sk, D) fp32.
+def _flash_fwd_impl(q5, kc, vc, window_f, scale, causal, q_offset, kv_chunk,
+                    sk_valid):
+    """q5: (B, Hkv, G, Sq, D) fp32; kc/vc: (B, Hkv, Sk, D) fp32, Sk a
+    multiple of kv_chunk whose keys from ``sk_valid`` on are padding.
     Returns (out, lse) with lse: (B, Hkv, G, Sq, 1)."""
     b, hkv, g, sq, d = q5.shape
     sk = kc.shape[2]
@@ -157,7 +175,8 @@ def _flash_fwd_impl(q5, kc, vc, window_f, scale, causal, q_offset, kv_chunk):
         vb = jax.lax.dynamic_slice_in_dim(vc, ik * kv_chunk, kv_chunk, 2)
         kpos = ik * kv_chunk + jnp.arange(kv_chunk)
         logits = jnp.einsum("bhgqd,bhkd->bhgqk", qf, kb)
-        mask = _mask_block_f(qpos, kpos, causal, window_f)[None, None, None]
+        mask = _mask_block_f(qpos, kpos, causal, window_f,
+                             sk_valid)[None, None, None]
         logits = jnp.where(mask, logits, NEG_INF)
         m_cur = jnp.max(logits, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -176,13 +195,14 @@ def _flash_fwd_impl(q5, kc, vc, window_f, scale, causal, q_offset, kv_chunk):
     return out, lse
 
 
-def _flash_fwd(q5, kc, vc, window_f, scale, causal, q_offset, kv_chunk):
+def _flash_fwd(q5, kc, vc, window_f, scale, causal, q_offset, kv_chunk,
+               sk_valid):
     out, lse = _flash_fwd_impl(q5, kc, vc, window_f, scale, causal, q_offset,
-                               kv_chunk)
+                               kv_chunk, sk_valid)
     return out, (q5, kc, vc, window_f, out, lse)
 
 
-def _flash_bwd(scale, causal, q_offset, kv_chunk, res, dout):
+def _flash_bwd(scale, causal, q_offset, kv_chunk, sk_valid, res, dout):
     q5, kc, vc, window_f, out, lse = res
     b, hkv, g, sq, d = q5.shape
     sk = kc.shape[2]
@@ -196,7 +216,8 @@ def _flash_bwd(scale, causal, q_offset, kv_chunk, res, dout):
         vb = jax.lax.dynamic_slice_in_dim(vc, ik * kv_chunk, kv_chunk, 2)
         kpos = ik * kv_chunk + jnp.arange(kv_chunk)
         logits = jnp.einsum("bhgqd,bhkd->bhgqk", qf, kb)
-        mask = _mask_block_f(qpos, kpos, causal, window_f)[None, None, None]
+        mask = _mask_block_f(qpos, kpos, causal, window_f,
+                             sk_valid)[None, None, None]
         logits = jnp.where(mask, logits, NEG_INF)
         p = jnp.exp(logits - lse)                          # (B,Hkv,G,Sq,K)
         dv = jnp.einsum("bhgqk,bhgqd->bhkd", p, dout)
@@ -219,21 +240,24 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def attention_xla(q, k, v, *, causal=True, window=None, scale=None,
                   kv_len=None, q_chunk=1024, kv_chunk=1024):
     """Flash attention in jnp: q-chunked outer map, custom-VJP kv-chunked
-    inner scan.  O(S) residuals; peak temp = B*Hq*q_chunk*kv_chunk logits."""
+    inner scan.  O(S) residuals; peak temp = B*Hq*q_chunk*kv_chunk logits.
+    Any sq/sk: the last q chunk may be short, and K/V are zero-padded to a
+    multiple of ``kv_chunk`` with the padded keys masked."""
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     g = hq // hkv
     scale = float(scale if scale is not None else 1.0 / np.sqrt(d))
     q_chunk = min(q_chunk, sq)
     kv_chunk = min(kv_chunk, sk)
-    assert sq % q_chunk == 0 and sk % kv_chunk == 0
-    nq = sq // q_chunk
+    k, v = _pad_to(k, kv_chunk), _pad_to(v, kv_chunk)
+    sk_pad = k.shape[2]
 
     if kv_len is not None:
         # serving path (no gradients): per-batch kv_len masking, plain scan
+        # (kv_len <= sk, so the padded keys are masked too)
         return _attention_kvlen(q, k, v, causal=causal, window=window,
                                 scale=scale, kv_len=kv_len,
-                                kv_chunk=kv_chunk)
+                                kv_chunk=kv_chunk, q_offset=sk - sq)
 
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
@@ -242,28 +266,30 @@ def attention_xla(q, k, v, *, causal=True, window=None, scale=None,
     # the custom-VJP nondiff args hashable and (b) lets causal chunks skip
     # KV blocks beyond their triangle entirely (no masked-out compute).
     outs = []
-    for iq in range(nq):
-        q_off = iq * q_chunk + (sk - sq)
+    for lo in range(0, sq, q_chunk):
+        qlen = min(q_chunk, sq - lo)
+        q_off = lo + (sk - sq)
         if causal:
-            kv_hi = min(sk, -(-(q_off + q_chunk) // kv_chunk) * kv_chunk)
+            kv_hi = min(sk_pad, -(-(q_off + qlen) // kv_chunk) * kv_chunk)
         else:
-            kv_hi = sk
-        qb = q[:, :, iq * q_chunk:(iq + 1) * q_chunk]
-        q5 = qb.astype(jnp.float32).reshape(b, hkv, g, q_chunk, d)
+            kv_hi = sk_pad
+        qb = q[:, :, lo:lo + qlen]
+        q5 = qb.astype(jnp.float32).reshape(b, hkv, g, qlen, d)
         wf = (jnp.float32(1e30) if window is None
               else jnp.asarray(window, jnp.float32))
         out = _flash(q5, kf[:, :, :kv_hi], vf[:, :, :kv_hi], wf, scale,
-                     causal, q_off, kv_chunk)
-        outs.append(out.reshape(b, hq, q_chunk, d).astype(q.dtype))
-    return outs[0] if nq == 1 else jnp.concatenate(outs, axis=2)
+                     causal, q_off, kv_chunk, sk)
+        outs.append(out.reshape(b, hq, qlen, d).astype(q.dtype))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=2)
 
 
-def _attention_kvlen(q, k, v, *, causal, window, scale, kv_len, kv_chunk):
+def _attention_kvlen(q, k, v, *, causal, window, scale, kv_len, kv_chunk,
+                     q_offset):
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     g = hq // hkv
     nk = sk // kv_chunk
-    qpos = jnp.arange(sq) + (sk - sq)
+    qpos = jnp.arange(sq) + q_offset
     qf = q.astype(jnp.float32).reshape(b, hkv, g, sq, d) * scale
 
     def kv_step(carry, ik):

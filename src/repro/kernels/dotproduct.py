@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params
 
 LANES = (8, 128)  # VPU-shaped accumulator tile
 BLOCK = LANES[0] * LANES[1]
@@ -60,7 +59,7 @@ def dotproduct_pallas(x, y, *, interpret=False):
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM(LANES, jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, y)[0, 0]

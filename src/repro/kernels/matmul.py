@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params
 
 # MXU-aligned default tiles (multiples of 128 on both matmul dims).
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 128, 128
@@ -58,7 +57,7 @@ def matmul_pallas(x, w, *, bm=DEFAULT_BM, bn=DEFAULT_BN, bk=DEFAULT_BK,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)

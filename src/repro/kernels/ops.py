@@ -20,7 +20,6 @@ import jax
 from . import ref
 from .attention import (attention_xla, decode_attention_xla,
                         flash_attention_pallas)
-from .pallas_compat import tpu_compiler_params  # noqa: F401 (re-export)
 from .conv2d import conv2d_pallas, conv2d_xla
 from .dotproduct import dotproduct_pallas, dotproduct_xla
 from .dropout import dropout_pallas, dropout_xla

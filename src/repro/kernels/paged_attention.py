@@ -49,7 +49,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF, decode_attention_xla
-from .pallas_compat import tpu_compiler_params
 
 
 # ---------------------------------------------------------------------------
@@ -138,7 +137,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, kv_len, *,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), kv_len.astype(jnp.int32),
@@ -262,7 +261,7 @@ def paged_prefill_attention_pallas(q, k_pool, v_pool, block_table, q_start,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, sq, d), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), q_start.astype(jnp.int32),

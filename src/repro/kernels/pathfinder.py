@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params
 
 _BIG = 3.0e38  # python float: jnp scalars would be captured as kernel consts
 
@@ -56,7 +55,7 @@ def pathfinder_pallas(w, *, interpret=False):
         out_specs=pl.BlockSpec((1, cols), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, cols), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, cols), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(w)[0]

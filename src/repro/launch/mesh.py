@@ -1,10 +1,9 @@
 """Production meshes.  Defined as functions so importing this module never
 touches jax device state (required by the dry-run contract).
 
-``make_mesh`` doubles as the jax API-drift shim: newer jax exposes
-``jax.sharding.AxisType`` and ``jax.make_mesh(..., axis_types=...)``, older
-releases have neither.  All mesh construction (src, tests, examples) goes
-through here so the drift is handled exactly once.
+All mesh construction (src, tests, examples) goes through ``make_mesh``,
+which marks every axis ``AxisType.Auto`` (the sharding-propagation mode
+the policies in ``repro.distributed`` are written for).
 """
 from __future__ import annotations
 
@@ -12,13 +11,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh across versions (with/without AxisType / axis_types)."""
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
     shape, axes = tuple(shape), tuple(axes)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

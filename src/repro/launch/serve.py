@@ -28,13 +28,16 @@ import json
 
 import jax
 
+from ..compile_cache import enable_compile_cache
 from ..configs import get_config, list_archs, smoke_config
 from ..models import build_model
 from ..serving import (DRIVERS, POLICIES, ROUTER_POLICIES, Attributor,
                        ClusterEngine, Request, ServeEngine, Tracer)
 
 
-def main():
+def main(argv=None):
+    """Serve ``argv`` (default: the command line).  Returns the engine
+    and each request's generated tokens, ``{rid: tokens}``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b", choices=list_archs())
     ap.add_argument("--smoke", action="store_true")
@@ -120,7 +123,7 @@ def main():
                          "bottleneck verdicts, fu_utilization; see "
                          "docs/observability.md)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
@@ -190,11 +193,14 @@ def main():
                   f"{' (final)' if ev.final else ''}")
         for rid in sorted(streamed):
             print(f"[serve] rid={rid} tokens={streamed[rid]}")
+        tokens = streamed
     else:
+        tokens = {}
         for r in eng.generate(reqs):
             print(f"[serve] rid={r.rid} ttft={r.prefill_ms:.1f}ms "
                   f"decode={r.decode_ms_per_tok:.1f}ms/tok "
                   f"tokens={r.tokens}")
+            tokens[r.rid] = r.tokens
     s = eng.last_stats
     paged = (f" block_util_peak={s.block_util_peak:.2f}"
              f" preempted={s.preempted} requeued={s.requeued}"
@@ -247,7 +253,9 @@ def main():
         n = tracer.export(args.trace)
         print(f"[trace] wrote {n} events to {args.trace} "
               "(open at https://ui.perfetto.dev)")
+    return eng, tokens
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,6 +12,7 @@ import argparse
 
 import jax
 
+from ..compile_cache import enable_compile_cache
 from ..configs import get_config, list_archs, smoke_config
 from ..data import MMapTokens, SyntheticTokens
 from ..distributed.sharding import ShardingPolicy
@@ -65,4 +66,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -54,23 +54,23 @@ def _mlstm_chunk(carry, qc, kc, vc, lf, li):
 
 def mlstm_parallel(q, k, v, i_gate, f_gate, *, chunk=256, state=None):
     """q/k: (B, H, S, dk), v: (B, H, S, dv), i_gate/f_gate: (B, H, S) raw.
-    Returns (y (B,H,S,dv), state)."""
+    Any S: the S % chunk positions after the last whole chunk run as one
+    shorter chunk.  Returns (y (B,H,S,dv), state)."""
     b, h, s, dk = q.shape
     dv = v.shape[-1]
     chunk = min(chunk, s)
-    assert s % chunk == 0
     nc = s // chunk
     k = k / np.sqrt(dk)
     lf = jax.nn.log_sigmoid(f_gate.astype(jnp.float32))
     li = i_gate.astype(jnp.float32)
+    seqs = (q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), lf, li)
 
-    def to_chunks(x, extra=()):
-        return jnp.moveaxis(x.reshape(b, h, nc, chunk, *extra), 2, 0)
+    def to_chunks(x):
+        x = x[:, :, :nc * chunk]
+        return jnp.moveaxis(
+            x.reshape(b, h, nc, chunk, *x.shape[3:]), 2, 0)
 
-    qs = to_chunks(q.astype(jnp.float32), (dk,))
-    ks = to_chunks(k.astype(jnp.float32), (dk,))
-    vs = to_chunks(v.astype(jnp.float32), (dv,))
-    lfs, lis = to_chunks(lf), to_chunks(li)
     if state is None:
         state = (jnp.zeros((b, h, dk, dv), jnp.float32),
                  jnp.zeros((b, h, dk), jnp.float32),
@@ -84,8 +84,11 @@ def mlstm_parallel(q, k, v, i_gate, f_gate, *, chunk=256, state=None):
     def step(carry, inp):
         return body(carry, *inp)
 
-    state, ys = jax.lax.scan(step, state, (qs, ks, vs, lfs, lis))
-    y = jnp.moveaxis(ys, 0, 2).reshape(b, h, s, dv)
+    state, ys = jax.lax.scan(step, state, tuple(map(to_chunks, seqs)))
+    y = jnp.moveaxis(ys, 0, 2).reshape(b, h, nc * chunk, dv)
+    if s % chunk:
+        state, y_tail = body(state, *(x[:, :, nc * chunk:] for x in seqs))
+        y = jnp.concatenate([y, y_tail], axis=2)
     return y.astype(v.dtype), state
 
 

@@ -79,29 +79,35 @@ class MachineSpec:
         return self.peak_flops / max(self.mem_bw, 1e-9)
 
     @classmethod
-    def from_tpu(cls, spec) -> "MachineSpec":
-        """From a :class:`repro.core.ppa.TpuSpec`."""
-        return cls(spec.name, spec.peak_bf16_flops, spec.hbm_bw)
+    def for_kind(cls, device_kind: str) -> "MachineSpec":
+        """The :data:`PEAKS` row of a jax ``device_kind``; a kind that is
+        not in the table raises instead of borrowing another device's
+        peaks."""
+        try:
+            return PEAKS[device_kind]
+        except KeyError:
+            raise ValueError(
+                f"no peak FLOP/s and bandwidth known for device_kind "
+                f"{device_kind!r}; add a sourced row to "
+                f"repro.serving.attribution.PEAKS (known: "
+                f"{sorted(PEAKS)})") from None
 
     @classmethod
     def detect(cls) -> "MachineSpec":
-        """Best-effort spec for the current jax backend.  TPU uses the
-        repo's v5e silicon constants; CPU/GPU get nominal figures — on
-        those backends the *absolute* utilization is indicative only,
-        but verdicts and trends are still comparable run-over-run (the
-        regression gate's tolerance bands account for this; see
-        docs/observability.md)."""
-        try:
-            import jax
-            plat = jax.default_backend()
-        except Exception:               # pragma: no cover - jax always here
-            plat = "cpu"
-        if plat == "tpu":
-            from ..core.ppa import TPU_V5E
-            return cls.from_tpu(TPU_V5E)
-        if plat == "gpu":
-            return cls("gpu-nominal", 50e12, 1.0e12)
-        return cls("cpu-nominal", 50e9, 25e9)
+        """The row of the first jax device's ``device_kind``."""
+        import jax
+        return cls.for_kind(jax.devices()[0].device_kind)
+
+
+#: Peaks per jax ``device_kind`` — the one table attribution reads.
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 and
+    # 819 GB/s HBM bandwidth per chip
+    "TPU v5 lite": MachineSpec("tpu-v5e", 197e12, 819e9),
+    # nominal, not a published or measured figure: it lets the CPU tests
+    # drive the classifier; no utilization from it is a device metric
+    "cpu": MachineSpec("cpu-nominal", 50e9, 25e9),
+}
 
 
 @dataclasses.dataclass(frozen=True)

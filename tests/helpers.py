@@ -103,15 +103,8 @@ def run_with_devices(script: str, n_devices: int = 8, timeout=600):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    # every snippet gets the version-compat mesh constructor plus a
-    # jax.shard_map alias (older jax only has jax.experimental.shard_map)
-    prelude = textwrap.dedent("""\
-        from repro.launch.mesh import make_mesh
-        import jax as _jax_compat
-        if not hasattr(_jax_compat, "shard_map"):
-            from jax.experimental.shard_map import shard_map as _shard_map
-            _jax_compat.shard_map = _shard_map
-        """)
+    # every snippet gets the repo's mesh constructor
+    prelude = "from repro.launch.mesh import make_mesh\n"
     proc = subprocess.run([sys.executable, "-c",
                            prelude + textwrap.dedent(script)],
                           capture_output=True, text=True, env=env,

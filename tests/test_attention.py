@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from helpers import given, settings, st
 
+from repro.kernels import ops
 from repro.kernels.attention import (attention_xla, decode_attention_xla,
                                      flash_attention_pallas)
 from repro.kernels.ref import attention_ref
@@ -38,6 +39,20 @@ def test_flash_matches_ref(causal, window, impl):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("s", [37, 200, 1100])
+@pytest.mark.parametrize("impl", ["interpret", "xla"])
+def test_ops_attention_any_length(impl, s, causal):
+    """Prompt lengths that are no multiple of the kernel tile (128) or the
+    XLA chunk (1024): the dispatch layer's default tiles must pad and mask,
+    not assert."""
+    q, k, v = qkv(b=1, hq=4, hkv=2, s=s, d=32)
+    want = attention_ref(q, k, v, causal=causal)
+    got = ops.attention(q, k, v, impl=impl, causal=causal)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
 @pytest.mark.parametrize("hq,hkv", [(8, 8), (8, 2), (8, 1)])
 def test_gqa_ratios(hq, hkv):
     q, k, v = qkv(hq=hq, hkv=hkv)
@@ -60,6 +75,25 @@ def test_flash_gradients_match_ref():
     for a, b in zip(gr, gf):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4,
                                    rtol=1e-3)
+
+
+def test_flash_gradients_ragged_length():
+    """The padded-key mask also holds in the custom-VJP backward."""
+    q, k, v = qkv(s=100, d=16)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    for causal in (True, False):
+        gr = jax.grad(loss(lambda q, k, v: attention_ref(
+            q, k, v, causal=causal)), argnums=(0, 1, 2))(q, k, v)
+        gf = jax.grad(loss(lambda q, k, v: attention_xla(
+            q, k, v, causal=causal, q_chunk=32, kv_chunk=32)),
+            argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gr, gf):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-4, rtol=1e-3)
 
 
 @given(st.integers(min_value=0, max_value=62))

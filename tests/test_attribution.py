@@ -51,6 +51,15 @@ def test_machine_spec_ridge():
     assert MachineSpec.detect().peak_flops > 0     # never degenerate
 
 
+def test_machine_spec_table_keyed_by_device_kind():
+    v5e = MachineSpec.for_kind("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.mem_bw) == (197e12, 819e9)
+    assert MachineSpec.detect() is MachineSpec.for_kind(
+        jax.devices()[0].device_kind)
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        MachineSpec.for_kind("TPU v9 imaginary")
+
+
 def test_classify_idle():
     at = Attributor(spec=SPEC)
     assert _classify(at, active=0) == "idle"

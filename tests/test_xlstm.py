@@ -44,6 +44,20 @@ def test_mlstm_parallel_matches_recurrence(chunk):
                                    rtol=1e-3)
 
 
+@pytest.mark.parametrize("s", [37, 70])
+def test_mlstm_parallel_ragged_length(s):
+    """A length that is no multiple of the chunk (a 700-token prompt at
+    the default chunk of 256) ends in one shorter chunk, exactly."""
+    q, k, v, ig, fg = make(s=s)
+    want, wstate = recurrent_oracle(q, k, v, ig, fg)
+    got, gstate = mlstm_parallel(q, k, v, ig, fg, chunk=16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4,
+                               rtol=1e-3)
+    for a, b_ in zip(gstate, wstate):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4,
+                                   rtol=1e-3)
+
+
 def test_mlstm_chunk_invariance():
     q, k, v, ig, fg = make(s=96)
     y1, _ = mlstm_parallel(q, k, v, ig, fg, chunk=16)
