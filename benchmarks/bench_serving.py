@@ -62,6 +62,7 @@ Emits ``name,us_per_call,derived`` CSV rows like the other benches:
   serving_attr_prefill,,bottleneck=...;chunks=...;gflops_s=...
   serving_nulltracer_overhead,,ns_per_guarded_call=...;bound=...
   serving_attr_overhead,,ns_per_guarded_call=...;bound=...
+  serving_phase_overhead,,us_per_step=...;bound=...
 
 The trailing rows are the observability gates (docs/observability.md):
 percentile latency rows come off the :class:`MetricsRegistry` every run
@@ -74,7 +75,10 @@ accounting (achieved FLOP/s and bytes/s vs peak, ``fu_utilization``,
 per-phase bottleneck verdicts) that ``tools/bench_compare.py`` gates
 against ``benchmarks/baselines/``; and the overhead rows bound the
 disabled-path cost of the default :class:`NullTracer` /
-:class:`NullAttributor`.
+:class:`NullAttributor` and of the engine's profiler phase spans with no
+profiler session (``python -c "from benchmarks.bench_serving import
+phase_overhead_us; print(phase_overhead_us())"`` times that loop alone,
+as on the chip's host).
 
 ``--smoke`` shrinks the trace/model work for the CI CPU regression gate;
 ``--json PATH`` additionally dumps every row for the CI artifact;
@@ -387,6 +391,34 @@ def _telemetry_report(model, params, vocab, n_reqs, long_new, cache_len,
     emit("serving_attr_overhead", "",
          f"ns_per_guarded_call={ns:.1f};bound={bound:.0f}ns;"
          f"calls={n_calls}")
+
+    # the phase spans open every decode step whether or not a profiler
+    # records them, so their off-path cost is paid by every user
+    us = phase_overhead_us()
+    bound_us = 20.0
+    assert us < bound_us, f"phase spans cost {us:.1f}us/step"
+    emit("serving_phase_overhead", "",
+         f"us_per_step={us:.2f};bound={bound_us:.0f}us")
+
+
+def phase_overhead_us(n_steps: int = 100_000) -> float:
+    """Microseconds a decode step pays for its five phase spans
+    (``telemetry.phase``) when no profiler session is active and no
+    tracer is attached."""
+    from repro.serving.telemetry import phase
+
+    t0 = time.perf_counter()
+    for k in range(n_steps):
+        with phase("engine.step", step_num=k):
+            with phase("engine.decode_dispatch"):
+                pass
+            with phase("engine.sample_dispatch"):
+                pass
+            with phase("engine.result_wait"):
+                pass
+            with phase("engine.emit"):
+                pass
+    return (time.perf_counter() - t0) / n_steps * 1e6
 
 
 def run(smoke: bool = False, json_path: str | None = None,

@@ -113,7 +113,7 @@ def main(argv=None):
     ap.add_argument("--metrics", nargs="?", const=True, default=None,
                     metavar="OUT.json",
                     help="print the metrics-registry summary (p50/p90/p99 "
-                         "TTFT+TPOT, queue age, occupancy/pool timelines); "
+                         "TTFT+TPOT, queue age, counters); "
                          "with a file argument, also write the stats + "
                          "registry snapshot as JSON so serve runs feed "
                          "tools/bench_compare.py like the benches do")

@@ -140,7 +140,8 @@ from . import kvcache
 from .attribution import NULL_ATTR, VERDICTS, dominant_verdict
 from .kvcache import BlockAllocator, PoolPressure, blocks_needed
 from .slo import make_policy
-from .telemetry import MONOTONIC, NULL_TRACER, MetricsRegistry
+from .telemetry import (MONOTONIC, NULL_TRACER, MetricsRegistry,
+                        install_gc_spans, phase)
 
 
 @dataclasses.dataclass
@@ -518,7 +519,8 @@ class ServeEngine:
     tracer / clock / track: telemetry (``repro.serving.telemetry``;
     ``docs/observability.md``).  ``tracer`` defaults to the no-op
     ``NULL_TRACER``; a real ``Tracer`` records request-lifecycle spans,
-    pool events, and per-step dispatch/device spans, host-side only (no
+    pool events, and the engine's phase spans (``engine.*``, which the
+    profiler records too; ``telemetry.phase``), host-side only (no
     compiled function depends on it — ``set_tracer`` may attach one to
     a warm engine).  ``clock`` injects the timebase every latency
     number is computed from (defaults to the tracer's clock when a
@@ -876,6 +878,7 @@ class ServeEngine:
                              "scheduler")
         if self._sess is not None:
             raise RuntimeError("a session is already open on this engine")
+        install_gc_spans()
         bsz = self.max_batch
         if self.kv_layout == "paged" and self._owns_pool:
             self.allocator.reset_peak()
@@ -1040,6 +1043,12 @@ class ServeEngine:
         admissions globally for victim selection (defaults to a per-engine
         counter); ``enqueue_t`` is the clock time the request entered the
         caller's queue (recorded as its queue-age sample)."""
+        with phase("engine.admit", self.tracer, self.track):
+            return self._admit(r, tag, extra_row, admit_seq, enqueue_t)
+
+    def _admit(self, r: Request, tag: int, extra_row: int,
+               admit_seq: int | None,
+               enqueue_t: float | None) -> Result | None:
         sess = self._require_session()
         slot = self.session_free_slot()
         if slot is None:
@@ -1176,23 +1185,24 @@ class ServeEngine:
             batch = {"tokens": jnp.asarray(prompt[None]),
                      **self._gather_extra([extra_row])}
         self._prefill_shapes.add(batch["tokens"].shape[1])
-        logits, sub = self._prefill(self.params, batch)
-        if replay:
-            logits, sub = self._replay_done(sub, r.done)
-            sess.metrics.counter("resume_replay_tokens").inc(len(r.done))
-        # sub["pos"] covers any model-side prefix (e.g. vlm patches)
-        prefill_pos = int(np.asarray(sub["pos"]).reshape(()))
-        self._check_budget(prefill_pos, r.max_new_tokens - len(r.done),
-                           r.rid)
-        if sess.cache is None:
-            sess.cache = self._cache_expand(sub, self.max_batch)
-        sess.cache = self._slot_write(sess.cache, sub, slot)
-        # the request's t-th token always uses stream index t, so a
-        # re-admitted (preempted) request resumes its stream at len(done)
-        tok = self._sample(logits, jnp.full((1,), r.temperature),
-                           sess.key, jnp.asarray([r.rid], np.int32),
-                           jnp.asarray([len(r.done)], np.int32))
-        tok = int(np.asarray(jax.block_until_ready(tok))[0])
+        with phase("engine.prefill", tr, self.track, rid=r.rid, tokens=plen):
+            logits, sub = self._prefill(self.params, batch)
+            if replay:
+                logits, sub = self._replay_done(sub, r.done)
+                sess.metrics.counter("resume_replay_tokens").inc(len(r.done))
+            # sub["pos"] covers any model-side prefix (e.g. vlm patches)
+            prefill_pos = int(np.asarray(sub["pos"]).reshape(()))
+            self._check_budget(prefill_pos, r.max_new_tokens - len(r.done),
+                               r.rid)
+            if sess.cache is None:
+                sess.cache = self._cache_expand(sub, self.max_batch)
+            sess.cache = self._slot_write(sess.cache, sub, slot)
+            # the request's t-th token always uses stream index t, so a
+            # re-admitted (preempted) request resumes its stream at len(done)
+            tok = self._sample(logits, jnp.full((1,), r.temperature),
+                               sess.key, jnp.asarray([r.rid], np.int32),
+                               jnp.asarray([len(r.done)], np.int32))
+            tok = int(np.asarray(jax.block_until_ready(tok))[0])
         t1 = self.clock.now()
         ttft_ms = (t1 - t0) * 1e3
         if tr.enabled:
@@ -1242,12 +1252,21 @@ class ServeEngine:
         blocks (``session_preempt``) - a retried step resumes a
         half-prefilled slot at its next chunk."""
         sess = self._require_session()
+        with phase("engine.step", self.tracer, self.track,
+                   step_num=sess.metrics.counter("decode_steps").n):
+            return self._step(sess)
+
+    def _step(self, sess: _Session) -> list[tuple[int, Result]]:
         bsz = self.max_batch
         if self.kv_layout == "paged":
             for i in range(bsz):
                 s = sess.slots[i]
                 if s is not None and s.chunks_done is not None:
-                    res = self._advance_prefill(sess, i, s)
+                    left = (blocks_needed(s.prefill_pos, self.block_size)
+                            - s.chunks_done)
+                    with phase("engine.prefill", self.tracer, self.track,
+                               rid=s.req.rid, chunks=left):
+                        res = self._advance_prefill(sess, i, s)
                     if res is not None:     # satisfied by prefill alone
                         # park it: a PoolPressure later in this same step
                         # must not lose an already-released slot's Result
@@ -1277,30 +1296,28 @@ class ServeEngine:
         # into the null block)
         tr = self.tracer
         t0 = self.clock.now()
-        logits, sess.cache = self._decode(self.params, sess.cache,
-                                          jnp.asarray(sess.toks))
+        with phase("engine.decode_dispatch", tr, self.track):
+            logits, sess.cache = self._decode(self.params, sess.cache,
+                                              jnp.asarray(sess.toks))
         # the decode launch returns asynchronously: [t0, t_disp] is host
         # dispatch (trace/lowering lookup + enqueue), the np.asarray
         # below blocks until the device result lands, so [t_disp, t1]
-        # is device compute + sampling + transfer
+        # is sampling dispatch + device compute + transfer
         t_disp = self.clock.now()
-        nxt = np.asarray(self._sample(
-            logits, jnp.asarray(sess.temps), sess.key,
-            jnp.asarray(sess.rids), jnp.asarray(sess.tok_idx)))
+        with phase("engine.sample_dispatch", tr, self.track):
+            nxt = self._sample(
+                logits, jnp.asarray(sess.temps), sess.key,
+                jnp.asarray(sess.rids), jnp.asarray(sess.tok_idx))
+        with phase("engine.result_wait", tr, self.track):
+            nxt = np.asarray(nxt)
         t1 = self.clock.now()
         dt = t1 - t0
         m = sess.metrics
         m.counter("decode_steps").inc()
         m.counter("busy_slot_steps").inc(len(active))
         m.counter("offered_slot_steps").inc(bsz)
-        m.timeline("occupancy").record(t1, len(active) / bsz)
-        if self.kv_layout == "paged":
-            m.timeline("pool_util").record(
-                t1, self.allocator.n_live / max(self.allocator.capacity, 1))
         if tr.enabled:
             tr.complete(self.track, "step", t0, t1, active=len(active))
-            tr.complete(self.track, "dispatch", t0, t_disp)
-            tr.complete(self.track, "device", t_disp, t1)
         at = self.attr
         if at.enabled:
             # shapes only (the post-step cache aliases the pre-step
@@ -1312,21 +1329,22 @@ class ServeEngine:
                                jnp.asarray(sess.toks)))
             at.record_step(m, tr, self.track, t0=t0, t_disp=t_disp, t1=t1,
                            active=len(active), width=bsz, cost=cost)
-        for i in active:
-            s = sess.slots[i]
-            s.tokens.append(int(nxt[i]))
-            self._emit_token(sess, s.req, int(nxt[i]),
-                             len(s.req.done) + len(s.tokens) - 1)
-            s.steps += 1
-            s.decode_s += dt
-            sess.toks[i, 0] = nxt[i]
-            sess.tok_idx[i] += 1
-            if len(s.req.done) + len(s.tokens) >= s.req.max_new_tokens:
-                finished.append((s.tag, self._finish(s)))
-                self._release(s, i)
-                sess.slots[i] = None   # freed: refilled on the next admit
-                if tr.enabled:
-                    self._trace_finish(s, i, t1)
+        with phase("engine.emit", tr, self.track):
+            for i in active:
+                s = sess.slots[i]
+                s.tokens.append(int(nxt[i]))
+                self._emit_token(sess, s.req, int(nxt[i]),
+                                 len(s.req.done) + len(s.tokens) - 1)
+                s.steps += 1
+                s.decode_s += dt
+                sess.toks[i, 0] = nxt[i]
+                sess.tok_idx[i] += 1
+                if len(s.req.done) + len(s.tokens) >= s.req.max_new_tokens:
+                    finished.append((s.tag, self._finish(s)))
+                    self._release(s, i)
+                    sess.slots[i] = None   # freed: refilled on next admit
+                    if tr.enabled:
+                        self._trace_finish(s, i, t1)
         return finished
 
     def _grow_slot(self, sess: _Session, i: int, s: _Slot) -> None:
@@ -1337,14 +1355,15 @@ class ServeEngine:
         so it can spend blocks other requests' promises hold back, and
         the allocator retires the promise atomically with the grant;
         under overcommit an empty pool surfaces as PoolPressure."""
-        blk = self._alloc_block(i, from_reservation=s.reserve_left > 0)
-        if s.reserve_left:
-            s.reserve_left -= 1
-        if self.tracer.enabled:
-            self.tracer.instant("pool", "kv_alloc", rid=s.req.rid, n=1,
-                                block=blk)
-        sess.cache = self._bt_set(sess.cache, i, len(s.blocks), blk)
-        s.blocks.append(blk)
+        with phase("engine.grow", self.tracer, self.track):
+            blk = self._alloc_block(i, from_reservation=s.reserve_left > 0)
+            if s.reserve_left:
+                s.reserve_left -= 1
+            if self.tracer.enabled:
+                self.tracer.instant("pool", "kv_alloc", rid=s.req.rid, n=1,
+                                    block=blk)
+            sess.cache = self._bt_set(sess.cache, i, len(s.blocks), blk)
+            s.blocks.append(blk)
 
     def _alloc_block(self, i: int, *, from_reservation: bool) -> int:
         """One pool allocation with overcommit pressure translation."""
@@ -1370,17 +1389,19 @@ class ServeEngine:
         from the allocation mutates nothing."""
         old = s.blocks[c]
         if self.allocator.refcount(old) > 1:
-            blk = self._alloc_block(i, from_reservation=s.reserve_left > 0)
-            if s.reserve_left:
-                s.reserve_left -= 1
-            sess.cache = self._copy_block(sess.cache, np.int32(blk),
-                                          np.int32(old))
-            sess.cache = self._bt_set(sess.cache, i, c, blk)
-            self.allocator.free([old], self.owner)
-            s.blocks[c] = blk
-            if self.tracer.enabled:
-                self.tracer.instant("pool", "kv_cow", rid=s.req.rid,
-                                    alloc=1, freed=1, block=blk)
+            with phase("engine.grow", self.tracer, self.track):
+                blk = self._alloc_block(i,
+                                        from_reservation=s.reserve_left > 0)
+                if s.reserve_left:
+                    s.reserve_left -= 1
+                sess.cache = self._copy_block(sess.cache, np.int32(blk),
+                                              np.int32(old))
+                sess.cache = self._bt_set(sess.cache, i, c, blk)
+                self.allocator.free([old], self.owner)
+                s.blocks[c] = blk
+                if self.tracer.enabled:
+                    self.tracer.instant("pool", "kv_cow", rid=s.req.rid,
+                                        alloc=1, freed=1, block=blk)
         s.shared_until = c
 
     def _chunk_tokens(self, r: Request, chunk: int) -> jnp.ndarray:
@@ -1614,17 +1635,19 @@ class ServeEngine:
         other holders, a registered last-reference block parks in the
         cached LRU — and park the block-table row on the null block so
         idle decode writes cannot touch recycled blocks."""
-        if self.kv_layout != "paged":
-            if self._slot_reset is not None and self._sess.cache is not None:
-                self._sess.cache = self._slot_reset(self._sess.cache, i)
-            return
-        if self.tracer.enabled and s.blocks:
-            self.tracer.instant("pool", "kv_free", rid=s.req.rid,
-                                n=len(s.blocks))
-        self.allocator.free(s.blocks, self.owner)
-        self.allocator.unreserve(s.reserve_left)
-        s.blocks, s.reserve_left = [], 0
-        self._sess.cache = self._slot_release(self._sess.cache, i)
+        with phase("engine.release", self.tracer, self.track):
+            if self.kv_layout != "paged":
+                if (self._slot_reset is not None
+                        and self._sess.cache is not None):
+                    self._sess.cache = self._slot_reset(self._sess.cache, i)
+                return
+            if self.tracer.enabled and s.blocks:
+                self.tracer.instant("pool", "kv_free", rid=s.req.rid,
+                                    n=len(s.blocks))
+            self.allocator.free(s.blocks, self.owner)
+            self.allocator.unreserve(s.reserve_left)
+            s.blocks, s.reserve_left = [], 0
+            self._sess.cache = self._slot_release(self._sess.cache, i)
 
     # ------------------------------------------------------------------
     # Continuous batching (slot pool + admission scheduler).

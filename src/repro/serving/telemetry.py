@@ -14,7 +14,7 @@ every replica step (dispatch vs device time) becomes a timestamped event
 that can be aggregated into percentiles or opened as a timeline in
 Perfetto.
 
-Three pieces:
+Four pieces:
 
 * :class:`Tracer` / :class:`NullTracer` - a span / instant / counter /
   flow event recorder.  ``NullTracer`` (the default everywhere) is a
@@ -40,6 +40,12 @@ Three pieces:
   preempt -> requeue handoffs as flow ("s"/"f") arrows, pool watermarks
   as counter ("C") series.
 
+* :class:`phase` - one named host interval on the profiler's clock
+  (``jax.profiler.TraceAnnotation``), so the engine's phases sit beside
+  the device's operations in a ``jax.profiler`` trace, mirrored onto an
+  enabled :class:`Tracer` under the same name.  :func:`install_gc_spans`
+  adds Python's generation-1 and -2 collections to the same trace.
+
 :func:`validate_lifecycle` is the event-stream conformance check the
 property suite runs over random traces: admits precede decodes, every
 preempt is answered by a requeue or abort, and per-request block
@@ -48,6 +54,8 @@ acquisitions balance releases.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import gc
 import json
 import math
 import threading
@@ -280,6 +288,88 @@ class Tracer(NullTracer):
         with open(path, "w") as f:
             json.dump(trace, f)
         return sum(e["ph"] != "M" for e in trace["traceEvents"])
+
+
+# ---------------------------------------------------------------------------
+# Profiler phases.
+# ---------------------------------------------------------------------------
+
+@functools.cache
+def _annotations():
+    # imported on first use: tools/check_trace.py loads this module
+    # without JAX (see the module doc)
+    from jax import profiler
+    return profiler.TraceAnnotation, profiler.StepTraceAnnotation
+
+
+class phase:
+    """Context manager naming one host interval.
+
+    Always enters a ``jax.profiler.TraceAnnotation(name)`` (a
+    ``StepTraceAnnotation`` when ``step_num`` is given), which lands on
+    the profiler's host plane, on the clock of the device's ``XLA Ops``;
+    with no profiler session active it records nothing and costs about a
+    microsecond.  When ``tracer`` is enabled the same interval is also
+    recorded as a complete event named ``name`` on ``track``.  ``args``
+    become the annotation's metadata and the event's args; keep them off
+    spans that open every step."""
+
+    __slots__ = ("_ann", "_tracer", "_track", "_name", "_args", "_t0")
+
+    def __init__(self, name: str, tracer=NULL_TRACER, track: str = "", *,
+                 step_num: int | None = None, **args):
+        ann, step_ann = _annotations()
+        if step_num is None:
+            self._ann = ann(name, **args)
+        else:
+            self._ann = step_ann(name, step_num=step_num, **args)
+        self._tracer = tracer
+        self._track = track
+        self._name = name
+        self._args = args
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._tracer.enabled:
+            self._t0 = self._tracer.clock.now()
+        return self
+
+    def __exit__(self, *exc):
+        if self._tracer.enabled:
+            self._tracer.complete(self._track, self._name, self._t0,
+                                  self._tracer.clock.now(), **self._args)
+        self._ann.__exit__(*exc)
+        return False
+
+
+#: profiler span names of the collections :func:`install_gc_spans` records
+GC_SPANS = {1: "python.gc.gen1", 2: "python.gc.gen2"}
+_gc_lock = threading.Lock()
+_gc_open: list = []     # the open collection's annotation, at most one
+
+
+def _gc_callback(when: str, info: dict) -> None:
+    # a collection runs to its end under the interpreter lock on the
+    # thread that triggered it, so "start" and "stop" pair up one at a
+    # time; generation 0 runs too often and too briefly to be worth one
+    name = GC_SPANS.get(info["generation"])
+    if name is None:
+        return
+    if when == "start":
+        ann = _annotations()[0](name)
+        ann.__enter__()
+        _gc_open.append(ann)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+def install_gc_spans() -> None:
+    """Record each generation-1 and -2 garbage collection as a profiler
+    span (:data:`GC_SPANS`).  Idempotent: the hook goes into
+    ``gc.callbacks`` once per process."""
+    with _gc_lock:
+        if _gc_callback not in gc.callbacks:
+            gc.callbacks.append(_gc_callback)
 
 
 # ---------------------------------------------------------------------------
