@@ -162,36 +162,61 @@ def xlstm_prefill(params, batch, cfg, *, cache_len=None):
 
 
 def xlstm_decode_step(params, cache, tokens, cfg):
+    """tokens: (B, 1).  Returns (logits (B, V), new cache).
+
+    The stacked recurrent state rides in the scans' *carry*: each mLSTM
+    layer reads its slice ``[g, j]`` and writes it back in place
+    (dynamic_update_slice), each sLSTM block its slice ``[g]``, and no
+    state leaf is a scan xs or ys.  With the cache donated, XLA aliases
+    the whole while-loop state, so a step reads and writes the matrix
+    memory only where the update needs it.  Carrying the state as xs/ys
+    instead costs four more full passes over ``m_c`` a step (slice the
+    group's state, write each layer into the inner ys, copy those into the
+    outer ys, copy the result into the donated output) and about 10 GB of
+    temporaries at 48 slots of xlstm-350m on a v5e
+    (tests/test_tpu_compile.py pins the carried form)."""
+    n_groups, m_per = _groups(cfg)
     x = embed_lookup(params["embed"], tokens)
+    state = {k: cache[k] for k in XLSTM_STATE_AXES}
+    m_keys = ("m_conv", "m_c", "m_n", "m_m")
+    s_keys = ("s_conv", "s_c", "s_n", "s_h", "s_m")
+
+    def at(a, i):
+        return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
 
     def group_body(carry, inp):
-        mparams, sparams, mc, mcc, mn, mm, sc, scc, sn, sh, sm = inp
+        x, st = carry
+        mparams, sparams, g = inp
 
-        def inner(c, lp_state):
-            lp, conv, cc, nn, m_ = lp_state
-            out, conv, (cc, nn, m_) = mlstm_block_decode(
-                lp, c, cfg.n_heads, conv, (cc, nn, m_),
+        def inner(c, lp_j):
+            x, st = c
+            lp, j = lp_j
+            conv, cc, nn, m_ = (at(at(st[k], g), j) for k in m_keys)
+            x, conv, (cc, nn, m_) = mlstm_block_decode(
+                lp, x, cfg.n_heads, conv, (cc, nn, m_),
                 norm_eps=cfg.norm_eps)
-            return out, (conv, cc, nn, m_)
+            st = dict(st)
+            for k, v in zip(m_keys, (conv, cc, nn, m_)):
+                st[k] = jax.lax.dynamic_update_slice(
+                    st[k], v[None, None], (g, j) + (0,) * v.ndim)
+            return (x, st), None
 
-        carry, mstates = jax.lax.scan(inner, carry,
-                                      (mparams, mc, mcc, mn, mm))
-        carry, s_conv, s_state = slstm_block_decode(
-            sparams, carry, cfg.n_heads, sc, (scc, sn, sh, sm),
+        (x, st), _ = jax.lax.scan(inner, (x, st),
+                                  (mparams, jnp.arange(m_per)))
+        s_conv, s_c, s_n, s_h, s_m = (at(st[k], g) for k in s_keys)
+        x, s_conv, s_state = slstm_block_decode(
+            sparams, x, cfg.n_heads, s_conv, (s_c, s_n, s_h, s_m),
             norm_eps=cfg.norm_eps)
-        return carry, (mstates, s_conv, *s_state)
+        st = dict(st)
+        for k, v in zip(s_keys, (s_conv, *s_state)):
+            st[k] = jax.lax.dynamic_update_index_in_dim(st[k], v, g, 0)
+        return (x, st), None
 
-    x, outs = jax.lax.scan(
-        group_body, x,
-        (params["mlstm"], params["slstm"], cache["m_conv"], cache["m_c"],
-         cache["m_n"], cache["m_m"], cache["s_conv"], cache["s_c"],
-         cache["s_n"], cache["s_h"], cache["s_m"]))
-    (m_conv, m_c, m_n, m_m), s_conv, s_c, s_n, s_h, s_m = outs
+    (x, state), _ = jax.lax.scan(
+        group_body, (x, state),
+        (params["mlstm"], params["slstm"], jnp.arange(n_groups)))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = jnp.einsum("bd,dv->bv", x[:, -1].astype(jnp.float32),
                         params["lm_head"].astype(jnp.float32))
     logits = logits[:, :cfg.vocab_size]
-    cache = {"m_conv": m_conv, "m_c": m_c, "m_n": m_n, "m_m": m_m,
-             "s_conv": s_conv, "s_c": s_c, "s_n": s_n, "s_h": s_h,
-             "s_m": s_m, "pos": cache["pos"] + 1}
-    return logits, cache
+    return logits, {**state, "pos": cache["pos"] + 1}

@@ -1,9 +1,11 @@
 """Compile-only checks for a described TPU v5e at published qwen3-0.6b
-widths: the TPU compiler refuses here what interpret mode cannot see
-(unaligned tiles, VMEM overuse, programs that do not fit).  Nothing runs;
-shapes only.  The topology is described inside a fixture, so importing
+and xlstm-350m widths: the TPU compiler refuses here what interpret mode
+cannot see (unaligned tiles, VMEM overuse, programs that do not fit), and
+reports what a program holds.  Nothing runs; shapes only.  The topology is described inside a fixture, so importing
 this file never loads the TPU library."""
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,3 +94,29 @@ def test_decode_paged_step_compiles_at_full_width(one_chip):
         compiled = jax.jit(model.decode_paged, donate_argnums=(1,)).lower(
             params, cache, toks).compile()
     _assert_kernel(compiled)
+
+
+def test_xlstm_decode_step_updates_state_in_place(one_chip):
+    """xlstm-350m (7:1) decode at 48 slots, the cache donated as the engine
+    donates it: the state is carried through the layer scans and updated
+    in place, so the step needs no state-sized temporaries (10.19 GB when
+    the state rode as scan xs/ys) and never copies the whole matrix
+    memory."""
+    cfg = dataclasses.replace(get_config("xlstm-350m"), slstm_every=8)
+    model = build_model(cfg)
+    slots = 48
+    params = _on(jax.eval_shape(model.init, jax.random.key(0)), one_chip)
+    cache = dict(model.cache_shapes(slots, 0))
+    cache["pos"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    cache = _on(cache, one_chip)
+    toks = _shape((slots, 1), jnp.int32, one_chip)
+    compiled = jax.jit(model.decode, donate_argnums=(1,)).lower(
+        params, cache, toks).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(s.size * s.dtype.itemsize for k, s in cache.items()
+                      if k != "pos")
+    assert mem.temp_size_in_bytes < 1e9
+    assert mem.alias_size_in_bytes >= state_bytes
+    m_c = "f32[" + ",".join(map(str, cache["m_c"].shape)) + "]"
+    full_copy = re.compile(re.escape(m_c) + r"\{[^}]*\} copy\(")
+    assert not full_copy.search(compiled.as_text())
