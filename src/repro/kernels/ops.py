@@ -108,35 +108,37 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, scale=None, window=None):
                                 window=window)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_table, kv_len, *,
+def paged_decode_attention(q, k_pool, v_pool, layer, block_table, kv_len, *,
                            impl=None, scale=None, window=None):
-    """Single-token attention against a paged KV pool via a block table.
+    """Single-token attention against layer ``layer`` of a stacked paged KV
+    pool (L, N, Hkv, bs, D) via a block table.
 
     Sliding windows ride the jnp gather path (the Pallas kernel keeps the
     prefix-mask fast path; traced per-layer windows would defeat its
     block-skip predicate anyway)."""
     impl = impl or default_impl()
     if impl == "xla" or window is not None:
-        return paged_decode_attention_xla(q, k_pool, v_pool, block_table,
-                                          kv_len, scale=scale, window=window)
-    return paged_decode_attention_pallas(q, k_pool, v_pool, block_table,
-                                         kv_len, scale=scale,
+        return paged_decode_attention_xla(q, k_pool, v_pool, layer,
+                                          block_table, kv_len, scale=scale,
+                                          window=window)
+    return paged_decode_attention_pallas(q, k_pool, v_pool, layer,
+                                         block_table, kv_len, scale=scale,
                                          interpret=impl == "interpret")
 
 
-def paged_prefill_attention(q, k_pool, v_pool, block_table, q_start, *,
-                            impl=None, scale=None, window=None):
-    """One prompt chunk's causal attention against a paged KV pool (the
-    chunk's K/V must already sit in its block).  Sliding windows ride the
-    per-block gather path, same as decode (traced per-layer windows would
-    defeat the Pallas block-skip predicate)."""
+def paged_prefill_attention(q, k_pool, v_pool, layer, block_table, q_start,
+                            *, impl=None, scale=None, window=None):
+    """One prompt chunk's causal attention against layer ``layer`` of a
+    stacked paged KV pool (the chunk's K/V must already sit in its block).
+    Sliding windows ride the per-block gather path, same as decode (traced
+    per-layer windows would defeat the Pallas block-skip predicate)."""
     impl = impl or default_impl()
     if impl == "xla" or window is not None:
-        return paged_prefill_attention_xla(q, k_pool, v_pool, block_table,
-                                           q_start, scale=scale,
+        return paged_prefill_attention_xla(q, k_pool, v_pool, layer,
+                                           block_table, q_start, scale=scale,
                                            window=window)
-    return paged_prefill_attention_pallas(q, k_pool, v_pool, block_table,
-                                          q_start, scale=scale,
+    return paged_prefill_attention_pallas(q, k_pool, v_pool, layer,
+                                          block_table, q_start, scale=scale,
                                           interpret=impl == "interpret")
 
 

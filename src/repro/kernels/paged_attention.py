@@ -1,22 +1,27 @@
 """Paged-attention kernels (GQA over a blocked KV pool): decode + prefill.
 
-KV lives in a global pool of fixed-size blocks — k_pool/v_pool:
-``(n_blocks, n_kv_heads, block_size, head_dim)`` — and each request owns an
-ordered *block table* row ``(max_blocks,)`` mapping its logical KV positions
-``[i * block_size, (i+1) * block_size)`` to pool block ids (vLLM's
-PagedAttention, Kwon et al. SOSP 2023).  Valid positions are a prefix:
-``kv_len[b]`` masks everything at or beyond the current length, so trailing
-table entries may point anywhere (the serving engine points them at the
-null block).
+KV lives in a global pool of fixed-size blocks, stacked over the layers —
+k_pool/v_pool: ``(n_layers, n_blocks, n_kv_heads, block_size, head_dim)`` —
+and each request owns an ordered *block table* row ``(max_blocks,)``
+mapping its logical KV positions ``[i * block_size, (i+1) * block_size)``
+to pool block ids (vLLM's PagedAttention, Kwon et al. SOSP 2023).  Every
+entry point takes the whole stack plus a ``layer`` index and reads only
+that layer's blocks, so the model's layer scan never slices a layer's pool
+out of the stack (a per-layer pool operand costs a copy of the whole
+reserved pool out and back per layer per launch).  Valid positions are a
+prefix: ``kv_len[b]`` masks everything at or beyond the current length, so
+trailing table entries may point anywhere (the serving engine points them
+at the null block).
 
 Two implementations:
 
 * ``pallas`` - scalar-prefetched block-table gather: the grid walks
   (batch, kv-head, block) and the k/v BlockSpec index_maps read the
-  prefetched block table, so each grid step DMAs exactly the one pool block
-  it needs; a flash-style online softmax accumulates across a request's
-  blocks.  No (B, S, D) contiguous KV is ever materialized.
-* ``xla`` - pure-jnp gather (``jnp.take`` of pool rows by block table)
+  prefetched layer and block table, so each grid step DMAs exactly the one
+  pool block ``(layer, bt[b, j], h)`` it needs; a flash-style online
+  softmax accumulates across a request's blocks.  No (B, S, D) contiguous
+  KV is ever materialized.
+* ``xla`` - pure-jnp gather (the pool rows ``[layer, block_table]``)
   followed by the dense masked decode attention.  Runs anywhere (CPU /
   interpret) and serves as the correctness oracle in tests.
 
@@ -34,9 +39,9 @@ dense ``(Hkv, prompt_len, D)`` cache:
   chunk at position p only pays for the ceil((p + Sq) / bs) blocks below
   its causal frontier.
 * ``xla`` - a scan over table entries gathering *one* pool block per step
-  (``jnp.take`` of a (B,) id vector) folded into an online softmax — the
-  CPU production path, O(block) memory, never a whole-table gather.  The
-  full-gather oracle lives in ``repro.kernels.ref``.
+  (the rows ``[layer, ids]`` of a (B,) id vector) folded into an online
+  softmax — the CPU production path, O(block) memory, never a whole-table
+  gather.  The full-gather oracle lives in ``repro.kernels.ref``.
 """
 from __future__ import annotations
 
@@ -55,9 +60,10 @@ from .attention import NEG_INF, decode_attention_xla
 # Pallas kernel.
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(bt_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(bt_ref, kvlen_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float, bs: int, g: int,
                   n_steps: int):
+    # layer_ref is read only by the k/v index maps
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -99,12 +105,13 @@ def _paged_kernel(bt_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, kv_len, *,
-                                  scale=None, interpret=False):
-    """q: (B, Hq, 1, D); k_pool/v_pool: (N, Hkv, bs, D);
-    block_table: (B, M) int32; kv_len: (B,) int32.  Returns (B, Hq, 1, D)."""
+def paged_decode_attention_pallas(q, k_pool, v_pool, layer, block_table,
+                                  kv_len, *, scale=None, interpret=False):
+    """q: (B, Hq, 1, D); k_pool/v_pool: (L, N, Hkv, bs, D); layer: int32
+    scalar; block_table: (B, M) int32; kv_len: (B,) int32.
+    Returns (B, Hq, 1, D)."""
     b, hq, _, d = q.shape
-    _, hkv, bs, _ = k_pool.shape
+    _, _, hkv, bs, _ = k_pool.shape
     g = hq // hkv
     m = block_table.shape[1]
     scale = float(scale if scale is not None else 1.0 / np.sqrt(d))
@@ -113,20 +120,22 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, kv_len, *,
     kern = functools.partial(_paged_kernel, scale=scale, bs=bs, g=g,
                              n_steps=m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hkv, m),
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
-                         lambda b_, h, j, bt, kl: (b_, h, 0, 0)),
+                         lambda b_, h, j, bt, kl, ly: (b_, h, 0, 0)),
             # the block-table gather: grid step (b, h, j) pulls pool block
-            # bt[b, j] for kv head h
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda b_, h, j, bt, kl: (bt[b_, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda b_, h, j, bt, kl: (bt[b_, j], h, 0, 0)),
+            # bt[b, j] of the layer for kv head h
+            pl.BlockSpec((None, 1, 1, bs, d),
+                         lambda b_, h, j, bt, kl, ly: (ly[0], bt[b_, j], h,
+                                                       0, 0)),
+            pl.BlockSpec((None, 1, 1, bs, d),
+                         lambda b_, h, j, bt, kl, ly: (ly[0], bt[b_, j], h,
+                                                       0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b_, h, j, bt, kl: (b_, h, 0, 0)),
+                               lambda b_, h, j, bt, kl, ly: (b_, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
@@ -141,7 +150,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, kv_len, *,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), kv_len.astype(jnp.int32),
-      q4, k_pool, v_pool)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q4, k_pool, v_pool)
     return out.reshape(b, hq, 1, d)
 
 
@@ -149,17 +158,18 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, kv_len, *,
 # Pure-JAX reference (CPU production path + correctness oracle).
 # ---------------------------------------------------------------------------
 
-def paged_decode_attention_xla(q, k_pool, v_pool, block_table, kv_len, *,
-                               scale=None, window=None):
-    """Gather each request's blocks into contiguous (B, Hkv, M*bs, D) KV
-    and run the dense masked decode attention.  Bitwise-identical math to
-    the dense layout when M*bs equals the dense cache length (positions at
-    or past kv_len are exact zeros in the softmax either way)."""
+def paged_decode_attention_xla(q, k_pool, v_pool, layer, block_table,
+                               kv_len, *, scale=None, window=None):
+    """Gather each request's blocks of ``layer`` into contiguous
+    (B, Hkv, M*bs, D) KV and run the dense masked decode attention.
+    Bitwise-identical math to the dense layout when M*bs equals the dense
+    cache length (positions at or past kv_len are exact zeros in the
+    softmax either way)."""
     b = q.shape[0]
-    _, hkv, bs, d = k_pool.shape
+    _, _, hkv, bs, d = k_pool.shape
     m = block_table.shape[1]
-    k = jnp.take(k_pool, block_table, axis=0)      # (B, M, Hkv, bs, D)
-    v = jnp.take(v_pool, block_table, axis=0)
+    k = k_pool[layer, block_table]                 # (B, M, Hkv, bs, D)
+    v = v_pool[layer, block_table]
     k = k.transpose(0, 2, 1, 3, 4).reshape(b, hkv, m * bs, d)
     v = v.transpose(0, 2, 1, 3, 4).reshape(b, hkv, m * bs, d)
     return decode_attention_xla(q, k, v, kv_len, scale=scale, window=window)
@@ -172,9 +182,11 @@ def paged_decode_attention_xla(q, k_pool, v_pool, block_table, kv_len, *,
 # through the table).
 # ---------------------------------------------------------------------------
 
-def _paged_prefill_kernel(bt_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
-                          m_ref, l_ref, acc_ref, *, scale: float, bs: int,
-                          g: int, sq: int, n_steps: int):
+def _paged_prefill_kernel(bt_ref, qstart_ref, layer_ref, q_ref, k_ref,
+                          v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                          scale: float, bs: int, g: int, sq: int,
+                          n_steps: int):
+    # layer_ref is read only by the k/v index maps
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -220,15 +232,16 @@ def _paged_prefill_kernel(bt_ref, qstart_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_prefill_attention_pallas(q, k_pool, v_pool, block_table, q_start,
-                                   *, scale=None, interpret=False):
+def paged_prefill_attention_pallas(q, k_pool, v_pool, layer, block_table,
+                                   q_start, *, scale=None, interpret=False):
     """q: (B, Hq, Sq, D) chunk queries starting at absolute position
-    q_start[b]; k_pool/v_pool: (N, Hkv, bs, D); block_table: (B, M) int32;
-    q_start: (B,) int32.  Returns (B, Hq, Sq, D).  Position 0 must be
-    attendable (q_start >= 0 and causal), so block 0 always contributes —
-    the online-softmax init never sees an all-masked first block."""
+    q_start[b]; k_pool/v_pool: (L, N, Hkv, bs, D); layer: int32 scalar;
+    block_table: (B, M) int32; q_start: (B,) int32.  Returns
+    (B, Hq, Sq, D).  Position 0 must be attendable (q_start >= 0 and
+    causal), so block 0 always contributes — the online-softmax init never
+    sees an all-masked first block."""
     b, hq, sq, d = q.shape
-    _, hkv, bs, _ = k_pool.shape
+    _, _, hkv, bs, _ = k_pool.shape
     g = hq // hkv
     m = block_table.shape[1]
     scale = float(scale if scale is not None else 1.0 / np.sqrt(d))
@@ -237,20 +250,23 @@ def paged_prefill_attention_pallas(q, k_pool, v_pool, block_table, q_start,
     kern = functools.partial(_paged_prefill_kernel, scale=scale, bs=bs, g=g,
                              sq=sq, n_steps=m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hkv, m),
         in_specs=[
             pl.BlockSpec((1, 1, g, sq, d),
-                         lambda b_, h, j, bt, qs: (b_, h, 0, 0, 0)),
+                         lambda b_, h, j, bt, qs, ly: (b_, h, 0, 0, 0)),
             # the block-table gather: grid step (b, h, j) pulls pool block
-            # bt[b, j] for kv head h
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda b_, h, j, bt, qs: (bt[b_, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda b_, h, j, bt, qs: (bt[b_, j], h, 0, 0)),
+            # bt[b, j] of the layer for kv head h
+            pl.BlockSpec((None, 1, 1, bs, d),
+                         lambda b_, h, j, bt, qs, ly: (ly[0], bt[b_, j], h,
+                                                       0, 0)),
+            pl.BlockSpec((None, 1, 1, bs, d),
+                         lambda b_, h, j, bt, qs, ly: (ly[0], bt[b_, j], h,
+                                                       0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, sq, d),
-                               lambda b_, h, j, bt, qs: (b_, h, 0, 0, 0)),
+                               lambda b_, h, j, bt, qs, ly: (b_, h, 0, 0,
+                                                             0)),
         scratch_shapes=[
             pltpu.VMEM((g * sq, 1), jnp.float32),
             pltpu.VMEM((g * sq, 1), jnp.float32),
@@ -265,20 +281,20 @@ def paged_prefill_attention_pallas(q, k_pool, v_pool, block_table, q_start,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), q_start.astype(jnp.int32),
-      q5, k_pool, v_pool)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q5, k_pool, v_pool)
     return out.reshape(b, hq, sq, d)
 
 
-def paged_prefill_attention_xla(q, k_pool, v_pool, block_table, q_start, *,
-                                scale=None, window=None):
+def paged_prefill_attention_xla(q, k_pool, v_pool, layer, block_table,
+                                q_start, *, scale=None, window=None):
     """CPU production path: walk the block table gathering one pool block
-    per step ((B, Hkv, bs, D) via ``jnp.take``) and fold it into a
+    of ``layer`` per step ((B, Hkv, bs, D)) and fold it into a
     flash-style online softmax.  Peak KV-side temp is a single block — the
     whole-table dense gather only exists in the ``ref`` oracle — and the
     walk stops at the batch's furthest causal frontier instead of paying
     for every (fully-masked) trailing table entry."""
     b, hq, sq, d = q.shape
-    _, hkv, bs, _ = k_pool.shape
+    _, _, hkv, bs, _ = k_pool.shape
     m = block_table.shape[1]
     g = hq // hkv
     scale = float(scale if scale is not None else 1.0 / np.sqrt(d))
@@ -289,8 +305,8 @@ def paged_prefill_attention_xla(q, k_pool, v_pool, block_table, q_start, *,
         m_prev, l_prev, acc = carry
         ids = jax.lax.dynamic_index_in_dim(block_table, j, 1,
                                            keepdims=False)       # (B,)
-        kb = jnp.take(k_pool, ids, axis=0).astype(jnp.float32)
-        vb = jnp.take(v_pool, ids, axis=0).astype(jnp.float32)
+        kb = k_pool[layer, ids].astype(jnp.float32)
+        vb = v_pool[layer, ids].astype(jnp.float32)
         kpos = j * bs + jnp.arange(bs)
         logits = jnp.einsum("bhgqd,bhkd->bhgqk", qf, kb)
         mask = kpos[None, None, :] <= qpos[:, :, None]           # (B, Sq, bs)

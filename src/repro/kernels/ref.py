@@ -166,6 +166,23 @@ def attention_ref(q, k, v, *, causal=True, window=None, scale=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def paged_decode_attention_ref(q, k_pool, v_pool, block_table, kv_len, *,
+                               scale=None):
+    """One-token attention against one layer's paged KV pool, fully
+    materialized.  q: (B, Hq, 1, D); k_pool/v_pool: (n_blocks, Hkv, bs, D);
+    block_table: (B, M); kv_len: (B,) — positions at or past it are masked.
+    Gathers the whole table into a dense (B, Hkv, M*bs, D) cache and runs
+    :func:`attention_ref`."""
+    b = q.shape[0]
+    _, hkv, bs, d = k_pool.shape
+    m = block_table.shape[1]
+    k = jnp.take(k_pool, block_table, axis=0)      # (B, M, Hkv, bs, D)
+    v = jnp.take(v_pool, block_table, axis=0)
+    k = k.transpose(0, 2, 1, 3, 4).reshape(b, hkv, m * bs, d)
+    v = v.transpose(0, 2, 1, 3, 4).reshape(b, hkv, m * bs, d)
+    return attention_ref(q, k, v, causal=False, scale=scale, kv_len=kv_len)
+
+
 def paged_prefill_attention_ref(q, k_pool, v_pool, block_table, q_start, *,
                                 scale=None, window=None):
     """Causal chunk attention against a paged KV pool, fully materialized.

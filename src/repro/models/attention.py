@@ -181,50 +181,61 @@ def attn_decode(p, x, k_cache, v_cache, kv_len, cfg, *, window=None,
     return jnp.einsum("bsh,hd->bsd", out, p["wo"]), k_cache, v_cache
 
 
-def attn_decode_paged(p, x, k_pool, v_pool, block_table, kv_len, cfg, *,
-                      window=None):
-    """One-token decode against a paged KV pool.  x: (B, 1, D);
-    k_pool/v_pool: (n_blocks, Hkv, block_size, D) for this layer;
-    block_table: (B, max_blocks) int32; kv_len: (B,) current lengths.
+def attn_decode_paged(p, x, k_pool, v_pool, layer, block_table, kv_len,
+                      cfg, *, window=None):
+    """One-token decode against layer ``layer`` of the stacked paged KV
+    pool.  x: (B, 1, D); k_pool/v_pool: (L, n_blocks, Hkv, block_size, D),
+    the whole stack; layer: int32 scalar; block_table: (B, max_blocks)
+    int32; kv_len: (B,) current lengths.
 
     The new token's KV lands in pool block ``block_table[b, kv_len // bs]``
-    at offset ``kv_len % bs`` (the engine guarantees that entry is
-    allocated before the step — idle slots' tables point at the null
-    block, so their stale writes stay in scratch).
+    of the layer at offset ``kv_len % bs`` (the engine guarantees that
+    entry is allocated before the step — idle slots' tables point at the
+    null block, so their stale writes stay in scratch).  The stack is
+    never sliced by layer: the kernel reads the layer's blocks straight
+    from it, and the write moves whole blocks (gather the B target blocks,
+    select the token in at its offset, scatter the blocks back), which
+    XLA does in place on the carried stack.  A one-row scatter
+    ``[layer, blk, :, off, :]`` instead makes layout assignment re-lay the
+    whole stack for it and copy it every layer.
     Returns (out, k_pool, v_pool)."""
     b = x.shape[0]
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     q, k, v = _project_decode_qkv(p, x, kv_len, cfg)
     blk = jnp.take_along_axis(block_table, (kv_len // bs)[:, None],
                               axis=1)[:, 0]
-    off = kv_len % bs
-    # per-row scatter into the pool: rows own distinct blocks, so writes
-    # never collide (idle rows all hit the null block — last write wins,
-    # and nothing reads it)
-    k_pool = k_pool.at[blk, :, off, :].set(k[:, :, 0, :])
-    v_pool = v_pool.at[blk, :, off, :].set(v[:, :, 0, :])
-    out = ops.paged_decode_attention(q, k_pool, v_pool, block_table,
+    at_off = (jnp.arange(bs)[None, :]
+              == (kv_len % bs)[:, None])[:, None, :, None]    # (B, 1, bs, 1)
+    # rows own distinct blocks, so the block writes never collide (idle
+    # rows all hit the null block — one of them wins, and nothing reads it)
+    k_blk = jnp.where(at_off, k.astype(k_pool.dtype), k_pool[layer, blk])
+    v_blk = jnp.where(at_off, v.astype(v_pool.dtype), v_pool[layer, blk])
+    k_pool = k_pool.at[layer, blk].set(k_blk)
+    v_pool = v_pool.at[layer, blk].set(v_blk)
+    out = ops.paged_decode_attention(q, k_pool, v_pool, layer, block_table,
                                      kv_len + 1, window=window)
     out = out.transpose(0, 2, 1, 3).reshape(b, 1, -1)
     return jnp.einsum("bsh,hd->bsd", out, p["wo"]), k_pool, v_pool
 
 
-def attn_prefill_paged(p, x, cfg, k_pool, v_pool, bt_row, chunk, *,
+def attn_prefill_paged(p, x, cfg, k_pool, v_pool, layer, bt_row, chunk, *,
                        window=None):
     """One ``block_size`` chunk of a paged prefill.  x: (1, bs, d_model) —
     the chunk's hidden states, covering absolute positions
     ``[chunk * bs, (chunk + 1) * bs)``; k_pool/v_pool:
-    (n_blocks, Hkv, bs, hd) for this layer; bt_row: (max_blocks,) int32
-    block table of the request being prefilled; ``chunk`` may be traced
+    (L, n_blocks, Hkv, bs, hd), the whole stack, of which this call reads
+    and writes layer ``layer``; bt_row: (max_blocks,) int32 block table of
+    the request being prefilled; ``layer`` and ``chunk`` may be traced
     (one compile serves every chunk of every prompt).
 
     The chunk's K/V are projected from just these bs rows and written
-    straight into pool block ``bt_row[chunk]`` — no ``(Hkv, prompt_len, D)``
-    cache is ever materialized — then the chunk's queries attend causally
-    over blocks ``0..chunk`` through the block table
+    straight into pool block ``(layer, bt_row[chunk])`` in place — no
+    ``(Hkv, prompt_len, D)`` cache and no per-layer pool is ever
+    materialized — then the chunk's queries attend causally over blocks
+    ``0..chunk`` through the block table
     (``ops.paged_prefill_attention``).  Returns (out, k_pool, v_pool)."""
     b, s, _ = x.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     assert b == 1 and s == bs, (
         f"paged prefill runs one request in block_size chunks: got batch "
         f"{b}, chunk {s} vs block_size {bs}")
@@ -235,9 +246,10 @@ def attn_prefill_paged(p, x, cfg, k_pool, v_pool, bt_row, chunk, *,
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos, cfg.rope_theta)
     blk = jnp.asarray(bt_row, jnp.int32)[jnp.asarray(chunk, jnp.int32)]
-    k_pool = jax.lax.dynamic_update_index_in_dim(k_pool, k[0], blk, 0)
-    v_pool = jax.lax.dynamic_update_index_in_dim(v_pool, v[0], blk, 0)
-    out = ops.paged_prefill_attention(q, k_pool, v_pool, bt_row[None],
+    at = (jnp.asarray(layer, jnp.int32), blk, 0, 0, 0)
+    k_pool = jax.lax.dynamic_update_slice(k_pool, k[0][None, None], at)
+    v_pool = jax.lax.dynamic_update_slice(v_pool, v[0][None, None], at)
+    out = ops.paged_prefill_attention(q, k_pool, v_pool, layer, bt_row[None],
                                       q_start[None], window=window)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, -1)
     return jnp.einsum("bsh,hd->bsd", out, p["wo"]), k_pool, v_pool

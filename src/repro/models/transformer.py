@@ -223,15 +223,18 @@ def decoder_prefill(params, batch, cfg, *, cache_len=None):
 
 def _decode_scan(params, tokens, k_all, v_all, cfg, attn_fn):
     """Shared one-token decode body for both KV layouts: embed, scan the
-    layer stack updating each layer's KV slice in place, final-norm, lm
-    head.  ``attn_fn(lp, h, kc, vc, window) -> (attn_out, kc, vc)`` is the
-    only layout-specific piece.
+    layer stack, final-norm, lm head.  ``attn_fn(lp, h, k_all, v_all,
+    layer, window) -> (attn_out, k_all, v_all)`` is the only
+    layout-specific piece: it gets the whole stacked caches and the layer
+    index, and updates that layer's entries in place.  The paged layout
+    reads and writes the layer's blocks inside the stack; the dense
+    layout slices its layer's strip out and writes it back.
 
-    The stacked KV caches ride in the scan *carry* and each layer updates
-    its slice in place (dynamic_update_index): with the cache donated, XLA
-    aliases the whole while-loop state.  Carrying them as scan xs/ys
-    double-buffers the full cache (~2.6x cache bytes of temp measured on
-    phi-3-vision decode_32k; see EXPERIMENTS.md §Perf).
+    The stacked KV caches ride in the scan *carry* and are never sliced
+    here: with the cache donated, XLA aliases the whole while-loop state.
+    Carrying them as scan xs/ys double-buffers the full cache (~2.6x cache
+    bytes of temp measured on phi-3-vision decode_32k; see EXPERIMENTS.md
+    §Perf).
     Returns (logits, k_all, v_all)."""
     x = embed_lookup(params["embed"], tokens)
     windows = windows_array(cfg)
@@ -242,15 +245,11 @@ def _decode_scan(params, tokens, k_all, v_all, cfg, attn_fn):
             (lp, idx), w = inp, None
         else:
             lp, idx, w = inp
-        kc = jax.lax.dynamic_index_in_dim(kc_all, idx, 0, keepdims=False)
-        vc = jax.lax.dynamic_index_in_dim(vc_all, idx, 0, keepdims=False)
         h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
-        a, kc, vc = attn_fn(lp["attn"], h, kc, vc, w)
+        a, kc_all, vc_all = attn_fn(lp["attn"], h, kc_all, vc_all, idx, w)
         x = x + a
         h = rmsnorm(lp["ln2"], x, cfg.norm_eps)
         x = x + _ffn(lp, h, cfg, exact=True)
-        kc_all = jax.lax.dynamic_update_index_in_dim(kc_all, kc, idx, 0)
-        vc_all = jax.lax.dynamic_update_index_in_dim(vc_all, vc, idx, 0)
         return (x, kc_all, vc_all), None
 
     idxs = jnp.arange(cfg.n_layers)
@@ -269,10 +268,16 @@ def decoder_decode_step(params, cache, tokens, cfg):
     slot-pool layout: each slot tracks its own position; the new KV lands
     at each row's own slot via the one-hot path in ``attn_decode``)."""
     pos = cache["pos"]
-    logits, k_new, v_new = _decode_scan(
-        params, tokens, cache["k"], cache["v"], cfg,
-        lambda lp, h, kc, vc, w: attn_decode(lp, h, kc, vc, pos, cfg,
-                                             window=w))
+
+    def attn_fn(lp, h, k_all, v_all, idx, w):
+        kc = jax.lax.dynamic_index_in_dim(k_all, idx, 0, keepdims=False)
+        vc = jax.lax.dynamic_index_in_dim(v_all, idx, 0, keepdims=False)
+        a, kc, vc = attn_decode(lp, h, kc, vc, pos, cfg, window=w)
+        return (a, jax.lax.dynamic_update_index_in_dim(k_all, kc, idx, 0),
+                jax.lax.dynamic_update_index_in_dim(v_all, vc, idx, 0))
+
+    logits, k_new, v_new = _decode_scan(params, tokens, cache["k"],
+                                        cache["v"], cfg, attn_fn)
     return logits, {"k": k_new, "v": v_new, "pos": pos + 1}
 
 
@@ -360,9 +365,10 @@ def decoder_prefill_paged(params, pcache, batch, slot, chunk, prefill_len,
     """One ``block_size`` chunk of a paged prefill for a single request.
 
     Chunked prefill: the chunk's hidden states run through the whole layer
-    stack; each layer projects the chunk's K/V, writes them straight into
-    the pool block ``pcache["bt"][slot, chunk]`` (installed by the engine
-    before the call), and attends causally over blocks ``0..chunk`` via
+    stack; each layer projects the chunk's K/V, writes them in place into
+    its layer's pool block ``pcache["bt"][slot, chunk]`` (installed by the
+    engine before the call) of the stacked pool, which the scan carries
+    and never slices, and attends causally over blocks ``0..chunk`` via
     the block table — the dense batch-1 ``(L, Hkv, prompt_len, hd)``
     prefill cache of the scatter-on-admit path never exists.  ``slot``,
     ``chunk`` and ``prefill_len`` may all be traced, so one compile serves
@@ -394,16 +400,13 @@ def decoder_prefill_paged(params, pcache, batch, slot, chunk, prefill_len,
             (lp, idx), w = inp, None
         else:
             lp, idx, w = inp
-        kp = jax.lax.dynamic_index_in_dim(kp_all, idx, 0, keepdims=False)
-        vp = jax.lax.dynamic_index_in_dim(vp_all, idx, 0, keepdims=False)
         h = rmsnorm(lp["ln1"], x, cfg.norm_eps)
-        a, kp, vp = attn_prefill_paged(lp["attn"], h, cfg, kp, vp, bt_row,
-                                       chunk, window=w)
+        a, kp_all, vp_all = attn_prefill_paged(lp["attn"], h, cfg, kp_all,
+                                               vp_all, idx, bt_row, chunk,
+                                               window=w)
         x = x + a
         h = rmsnorm(lp["ln2"], x, cfg.norm_eps)
         x = constrain(x + _ffn(lp, h, cfg, exact=True), "hidden")
-        kp_all = jax.lax.dynamic_update_index_in_dim(kp_all, kp, idx, 0)
-        vp_all = jax.lax.dynamic_update_index_in_dim(vp_all, vp, idx, 0)
         return (x, kp_all, vp_all), None
 
     idxs = jnp.arange(cfg.n_layers)
@@ -429,6 +432,6 @@ def decoder_decode_step_paged(params, pcache, tokens, cfg):
     pos, bt = pcache["pos"], pcache["bt"]
     logits, kp, vp = _decode_scan(
         params, tokens, pcache["kp"], pcache["vp"], cfg,
-        lambda lp, h, kc, vc, w: attn_decode_paged(lp, h, kc, vc, bt, pos,
-                                                   cfg, window=w))
+        lambda lp, h, kp_all, vp_all, idx, w: attn_decode_paged(
+            lp, h, kp_all, vp_all, idx, bt, pos, cfg, window=w))
     return logits, {"kp": kp, "vp": vp, "bt": bt, "pos": pos + 1}

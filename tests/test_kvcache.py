@@ -13,8 +13,11 @@ from repro.kernels.paged_attention import (paged_decode_attention_pallas,
                                            paged_decode_attention_xla,
                                            paged_prefill_attention_pallas,
                                            paged_prefill_attention_xla)
-from repro.kernels.ref import paged_prefill_attention_ref
+from repro.kernels.ref import (paged_decode_attention_ref,
+                               paged_prefill_attention_ref)
 from repro.models import build_model
+from repro.models.attention import attn_decode_paged
+from repro.models.transformer import _decode_scan
 from repro.serving import (BlockAllocator, Request, ServeEngine,
                            blocks_needed, prefix_chain_keys)
 
@@ -859,21 +862,23 @@ def test_prefix_cache_rejects_vlm():
 # Paged-attention kernel vs reference path.
 # ---------------------------------------------------------------------------
 
-def _rand_paged_case(key, *, n_blocks=9, hkv=2, bs=16, d=16, b=3, m=4, g=3):
+def _rand_paged_case(key, *, n_layers=3, n_blocks=9, hkv=2, bs=16, d=16,
+                     b=3, m=4, g=3):
+    """A stacked (L, N, Hkv, bs, D) pool and the layer the kernels read."""
     k1, k2, k3 = jax.random.split(key, 3)
-    kp = jax.random.normal(k1, (n_blocks, hkv, bs, d), jnp.float32)
-    vp = jax.random.normal(k2, (n_blocks, hkv, bs, d), jnp.float32)
+    kp = jax.random.normal(k1, (n_layers, n_blocks, hkv, bs, d), jnp.float32)
+    vp = jax.random.normal(k2, (n_layers, n_blocks, hkv, bs, d), jnp.float32)
     q = jax.random.normal(k3, (b, hkv * g, 1, d), jnp.float32)
     bt = jnp.asarray(
         np.array([[1, 2, 3, 4], [5, 6, 0, 0], [7, 8, 0, 0]]), jnp.int32)
     kv_len = jnp.asarray([64, 23, 17], jnp.int32)
-    return q, kp, vp, bt, kv_len
+    return q, kp, vp, jnp.int32(1), bt, kv_len
 
 
 def test_paged_kernel_matches_reference():
-    q, kp, vp, bt, kv_len = _rand_paged_case(jax.random.key(1))
-    ref = paged_decode_attention_xla(q, kp, vp, bt, kv_len)
-    got = paged_decode_attention_pallas(q, kp, vp, bt, kv_len,
+    q, kp, vp, ly, bt, kv_len = _rand_paged_case(jax.random.key(1))
+    ref = paged_decode_attention_xla(q, kp, vp, ly, bt, kv_len)
+    got = paged_decode_attention_pallas(q, kp, vp, ly, bt, kv_len,
                                         interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -882,11 +887,11 @@ def test_paged_kernel_matches_reference():
 def test_paged_kernel_partial_block_boundaries():
     """kv_len at and just past block boundaries (the masked tail of a
     block and a fully masked trailing block)."""
-    q, kp, vp, bt, _ = _rand_paged_case(jax.random.key(2))
+    q, kp, vp, ly, bt, _ = _rand_paged_case(jax.random.key(2))
     for lens in ([16, 16, 16], [1, 32, 33], [48, 17, 1]):
         kv_len = jnp.asarray(lens, jnp.int32)
-        ref = paged_decode_attention_xla(q, kp, vp, bt, kv_len)
-        got = paged_decode_attention_pallas(q, kp, vp, bt, kv_len,
+        ref = paged_decode_attention_xla(q, kp, vp, ly, bt, kv_len)
+        got = paged_decode_attention_pallas(q, kp, vp, ly, bt, kv_len,
                                             interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5, err_msg=str(lens))
@@ -895,30 +900,30 @@ def test_paged_kernel_partial_block_boundaries():
 def test_paged_kernel_ignores_garbage_past_kv_len():
     """Entries at or past kv_len must not leak into the output, whatever
     the trailing block-table ids point at."""
-    q, kp, vp, bt, kv_len = _rand_paged_case(jax.random.key(3))
-    ref = paged_decode_attention_xla(q, kp, vp, bt, kv_len)
-    kp2 = kp.at[0].set(1e6)             # null block: rows 1/2 padding
-    vp2 = vp.at[0].set(-1e6)
-    ref2 = paged_decode_attention_xla(q, kp2, vp2, bt, kv_len)
+    q, kp, vp, ly, bt, kv_len = _rand_paged_case(jax.random.key(3))
+    ref = paged_decode_attention_xla(q, kp, vp, ly, bt, kv_len)
+    kp2 = kp.at[ly, 0].set(1e6)         # null block: rows 1/2 padding
+    vp2 = vp.at[ly, 0].set(-1e6)
+    ref2 = paged_decode_attention_xla(q, kp2, vp2, ly, bt, kv_len)
     np.testing.assert_allclose(np.asarray(ref2[1:]), np.asarray(ref[1:]),
                                rtol=1e-6, atol=1e-6)
-    got2 = paged_decode_attention_pallas(q, kp2, vp2, bt, kv_len,
+    got2 = paged_decode_attention_pallas(q, kp2, vp2, ly, bt, kv_len,
                                          interpret=True)
     np.testing.assert_allclose(np.asarray(got2[1:]), np.asarray(ref[1:]),
                                rtol=1e-5, atol=1e-5)
 
 
 def test_paged_kernel_via_ops_dispatch():
-    q, kp, vp, bt, kv_len = _rand_paged_case(jax.random.key(4))
-    ref = ops.paged_decode_attention(q, kp, vp, bt, kv_len, impl="xla")
-    got = ops.paged_decode_attention(q, kp, vp, bt, kv_len,
+    q, kp, vp, ly, bt, kv_len = _rand_paged_case(jax.random.key(4))
+    ref = ops.paged_decode_attention(q, kp, vp, ly, bt, kv_len, impl="xla")
+    got = ops.paged_decode_attention(q, kp, vp, ly, bt, kv_len,
                                      impl="interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     # sliding windows ride the gather/reference path in every impl
-    win = ops.paged_decode_attention(q, kp, vp, bt, kv_len,
+    win = ops.paged_decode_attention(q, kp, vp, ly, bt, kv_len,
                                      impl="interpret", window=8)
-    winref = paged_decode_attention_xla(q, kp, vp, bt, kv_len, window=8)
+    winref = paged_decode_attention_xla(q, kp, vp, ly, bt, kv_len, window=8)
     np.testing.assert_allclose(np.asarray(win), np.asarray(winref),
                                rtol=1e-6, atol=1e-6)
 
@@ -927,24 +932,26 @@ def test_paged_kernel_via_ops_dispatch():
 # Paged chunked-prefill kernel vs reference paths.
 # ---------------------------------------------------------------------------
 
-def _rand_prefill_case(key, *, n_blocks=9, hkv=2, bs=8, d=16, b=3, m=4,
-                       g=2):
+def _rand_prefill_case(key, *, n_layers=3, n_blocks=9, hkv=2, bs=8, d=16,
+                       b=3, m=4, g=2):
+    """A stacked (L, N, Hkv, bs, D) pool and the layer the kernels read."""
     k1, k2, k3 = jax.random.split(key, 3)
-    kp = jax.random.normal(k1, (n_blocks, hkv, bs, d), jnp.float32)
-    vp = jax.random.normal(k2, (n_blocks, hkv, bs, d), jnp.float32)
+    kp = jax.random.normal(k1, (n_layers, n_blocks, hkv, bs, d), jnp.float32)
+    vp = jax.random.normal(k2, (n_layers, n_blocks, hkv, bs, d), jnp.float32)
     q = jax.random.normal(k3, (b, hkv * g, bs, d), jnp.float32)
     bt = jnp.asarray(
         np.array([[1, 2, 3, 4], [5, 6, 0, 0], [7, 8, 0, 0]]), jnp.int32)
     # rows sit at chunks 3, 1, 0: causal frontiers mid-table, early, first
     q_start = jnp.asarray([24, 8, 0], jnp.int32)
-    return q, kp, vp, bt, q_start
+    return q, kp, vp, jnp.int32(2), bt, q_start
 
 
 def test_paged_prefill_kernel_matches_reference():
-    q, kp, vp, bt, qs = _rand_prefill_case(jax.random.key(5))
-    ref = paged_prefill_attention_ref(q, kp, vp, bt, qs)
-    xla = paged_prefill_attention_xla(q, kp, vp, bt, qs)
-    pal = paged_prefill_attention_pallas(q, kp, vp, bt, qs, interpret=True)
+    q, kp, vp, ly, bt, qs = _rand_prefill_case(jax.random.key(5))
+    ref = paged_prefill_attention_ref(q, kp[ly], vp[ly], bt, qs)
+    xla = paged_prefill_attention_xla(q, kp, vp, ly, bt, qs)
+    pal = paged_prefill_attention_pallas(q, kp, vp, ly, bt, qs,
+                                         interpret=True)
     np.testing.assert_allclose(np.asarray(xla), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
@@ -955,12 +962,12 @@ def test_paged_prefill_kernel_chunk_positions():
     """Every chunk index, including the first (block 0 must always
     contribute — the online softmax init relies on it) and the last
     (frontier at the table's end)."""
-    q, kp, vp, bt, _ = _rand_prefill_case(jax.random.key(6))
+    q, kp, vp, ly, bt, _ = _rand_prefill_case(jax.random.key(6))
     for starts in ([0, 0, 0], [8, 16, 24], [24, 24, 24]):
         qs = jnp.asarray(starts, jnp.int32)
-        ref = paged_prefill_attention_ref(q, kp, vp, bt, qs)
-        xla = paged_prefill_attention_xla(q, kp, vp, bt, qs)
-        pal = paged_prefill_attention_pallas(q, kp, vp, bt, qs,
+        ref = paged_prefill_attention_ref(q, kp[ly], vp[ly], bt, qs)
+        xla = paged_prefill_attention_xla(q, kp, vp, ly, bt, qs)
+        pal = paged_prefill_attention_pallas(q, kp, vp, ly, bt, qs,
                                              interpret=True)
         np.testing.assert_allclose(np.asarray(xla), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5, err_msg=str(starts))
@@ -972,27 +979,152 @@ def test_paged_prefill_kernel_ignores_blocks_past_frontier():
     """Blocks beyond a chunk's causal frontier must not leak into the
     output whatever their table entries point at (the engine leaves
     trailing entries on the null block)."""
-    q, kp, vp, bt, qs = _rand_prefill_case(jax.random.key(7))
-    ref = paged_prefill_attention_ref(q, kp, vp, bt, qs)
-    kp2 = kp.at[0].set(1e6)            # null block: rows 1/2 trailing ids
-    vp2 = vp.at[0].set(-1e6)
+    q, kp, vp, ly, bt, qs = _rand_prefill_case(jax.random.key(7))
+    ref = paged_prefill_attention_ref(q, kp[ly], vp[ly], bt, qs)
+    kp2 = kp.at[ly, 0].set(1e6)        # null block: rows 1/2 trailing ids
+    vp2 = vp.at[ly, 0].set(-1e6)
     for fn in (paged_prefill_attention_xla,
                lambda *a: paged_prefill_attention_pallas(*a,
                                                          interpret=True)):
-        got = fn(q, kp2, vp2, bt, qs)
+        got = fn(q, kp2, vp2, ly, bt, qs)
         np.testing.assert_allclose(np.asarray(got[1:]), np.asarray(ref[1:]),
                                    rtol=1e-5, atol=1e-5)
 
 
 def test_paged_prefill_kernel_via_ops_dispatch():
-    q, kp, vp, bt, qs = _rand_prefill_case(jax.random.key(8))
-    ref = ops.paged_prefill_attention(q, kp, vp, bt, qs, impl="xla")
-    got = ops.paged_prefill_attention(q, kp, vp, bt, qs, impl="interpret")
+    q, kp, vp, ly, bt, qs = _rand_prefill_case(jax.random.key(8))
+    ref = ops.paged_prefill_attention(q, kp, vp, ly, bt, qs, impl="xla")
+    got = ops.paged_prefill_attention(q, kp, vp, ly, bt, qs,
+                                      impl="interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     # sliding windows ride the per-block gather path in every impl
-    win = ops.paged_prefill_attention(q, kp, vp, bt, qs, impl="interpret",
-                                      window=5)
-    winref = paged_prefill_attention_ref(q, kp, vp, bt, qs, window=5)
+    win = ops.paged_prefill_attention(q, kp, vp, ly, bt, qs,
+                                      impl="interpret", window=5)
+    winref = paged_prefill_attention_ref(q, kp[ly], vp[ly], bt, qs,
+                                         window=5)
     np.testing.assert_allclose(np.asarray(win), np.asarray(winref),
                                rtol=1e-5, atol=1e-5)
+
+# ---------------------------------------------------------------------------
+# The stacked pool: kernels read a layer of it, the model step writes it in
+# place.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_paged_kernels_read_the_layer_of_the_stack(kind, impl):
+    """On a stacked pool and layer l, with random block tables, each paged
+    kernel gives bitwise what it gives on ``pool[l]`` alone as a one-layer
+    stack, and both match the per-layer oracle in ``kernels/ref.py``."""
+    rng = np.random.default_rng(17)
+    n_layers, n_blocks, hkv, bs, d, b, m, g = 3, 13, 2, 8, 16, 3, 5, 2
+    kp = jnp.asarray(rng.standard_normal((n_layers, n_blocks, hkv, bs, d)),
+                     jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((n_layers, n_blocks, hkv, bs, d)),
+                     jnp.float32)
+    bt = jnp.asarray(rng.integers(0, n_blocks, (b, m)), jnp.int32)
+    if kind == "decode":
+        q = jnp.asarray(rng.standard_normal((b, hkv * g, 1, d)), jnp.float32)
+        lens = jnp.asarray(rng.integers(1, m * bs + 1, b), jnp.int32)
+        fn, oracle = ops.paged_decode_attention, paged_decode_attention_ref
+    else:
+        q = jnp.asarray(rng.standard_normal((b, hkv * g, bs, d)), jnp.float32)
+        lens = jnp.asarray(rng.integers(0, m, b) * bs, jnp.int32)  # q_start
+        fn, oracle = ops.paged_prefill_attention, paged_prefill_attention_ref
+    for layer in range(n_layers):
+        got = fn(q, kp, vp, jnp.int32(layer), bt, lens, impl=impl)
+        alone = fn(q, kp[layer][None], vp[layer][None], jnp.int32(0), bt,
+                   lens, impl=impl)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(alone),
+                                      err_msg=f"layer {layer}")
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(oracle(q, kp[layer], vp[layer], bt,
+                                               lens)),
+            rtol=1e-5, atol=1e-5, err_msg=f"layer {layer}")
+
+
+def _decode_paged_slicing_layers(params, pcache, tokens, cfg):
+    """The paged decode step as it was before the kernels took the stack:
+    each layer's pool is sliced out of the stack, handed to the attention
+    as a one-layer stack and written back whole."""
+    bt, pos = pcache["bt"], pcache["pos"]
+
+    def attn_fn(lp, h, kp_all, vp_all, idx, w):
+        a, kp, vp = attn_decode_paged(lp, h, kp_all[idx][None],
+                                      vp_all[idx][None], 0, bt, pos, cfg,
+                                      window=w)
+        return a, kp_all.at[idx].set(kp[0]), vp_all.at[idx].set(vp[0])
+
+    logits, kp, vp = _decode_scan(params, tokens, pcache["kp"],
+                                  pcache["vp"], cfg, attn_fn)
+    return logits, {"kp": kp, "vp": vp, "bt": bt, "pos": pos + 1}
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_paged_decode_in_place_matches_layer_slices_and_dense(
+        model_and_params, impl):
+    """Smoke qwen3 paged decode over several steps, the stacked pool
+    updated in place: rows 0 and 1 share a prefix block, row 3 is idle on
+    the null block.  Logits and every pool block but the null block
+    (scratch that nothing reads) are bitwise those of the step that
+    slices each layer out of the stack; with the xla impl, whose math
+    is the dense layout's, logits and each row's KV are bitwise those of
+    the dense-strip layout too (the Pallas kernel's online softmax rounds
+    differently from the dense softmax)."""
+    cfg, model, params = model_and_params
+    rng = np.random.default_rng(5)
+    n_l, hkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim_resolved
+    bs, m, b, steps = BLOCK, CACHE_LEN // BLOCK, 4, 3
+    pos = np.array([20, 35, 5, 0], np.int32)
+    active = np.array([True, True, True, False])
+    bt = np.array([[1, 2, 0, 0], [1, 3, 4, 0], [5, 0, 0, 0], [0, 0, 0, 0]],
+                  np.int32)
+    kv = rng.standard_normal((2, n_l, b, hkv, CACHE_LEN, hd)).astype(
+        np.float32)
+    kv[:, :, 1, :, :bs] = kv[:, :, 0, :, :bs]          # the shared block
+    kv[:, :, ~active] = 0.0
+    for r in range(b):
+        kv[:, :, r, :, pos[r]:] = 0.0
+    kv = jnp.asarray(kv, jnp.bfloat16)
+    pool = jnp.asarray(rng.standard_normal((2, n_l, 7, hkv, bs, hd)),
+                       jnp.bfloat16)                   # garbage everywhere
+    for r in np.flatnonzero(active):
+        for j in range(-(-int(pos[r]) // bs)):
+            pool = pool.at[:, :, bt[r, j]].set(
+                kv[:, :, r, :, j * bs:(j + 1) * bs])
+    paged = {"kp": pool[0], "vp": pool[1], "bt": jnp.asarray(bt),
+             "pos": jnp.asarray(pos)}
+    sliced = dict(paged)
+    dense = {"k": kv[0], "v": kv[1], "pos": jnp.asarray(pos)}
+    toks = rng.integers(0, cfg.vocab_size, (steps, b, 1)).astype(np.int32)
+    with ops.impl_scope(impl):
+        # fresh functions: a jitted function keeps the implementation it
+        # was first traced under
+        step = jax.jit(lambda p, c, t: model.decode_paged(p, c, t))
+        ref_step = jax.jit(
+            lambda p, c, t: _decode_paged_slicing_layers(p, c, t, cfg))
+        dense_step = jax.jit(lambda p, c, t: model.decode(p, c, t))
+        for t in range(steps):
+            logits, paged = step(params, paged, toks[t])
+            ref_logits, sliced = ref_step(params, sliced, toks[t])
+            d_logits, dense = dense_step(params, dense, toks[t])
+            np.testing.assert_array_equal(np.asarray(logits),
+                                          np.asarray(ref_logits))
+            if impl == "xla":
+                np.testing.assert_array_equal(np.asarray(logits[active]),
+                                              np.asarray(d_logits[active]))
+    for name in ("kp", "vp"):
+        np.testing.assert_array_equal(np.asarray(paged[name][:, 1:]),
+                                      np.asarray(sliced[name][:, 1:]))
+    np.testing.assert_array_equal(np.asarray(paged["pos"]), pos + steps)
+    if impl == "xla":
+        for name, strip in (("kp", "k"), ("vp", "v")):
+            for r in np.flatnonzero(active):
+                n = int(pos[r]) + steps
+                got = paged[name][:, bt[r]]        # (L, M, Hkv, bs, hd)
+                got = got.transpose(0, 2, 1, 3, 4).reshape(
+                    n_l, hkv, m * bs, hd)[:, :, :n]
+                np.testing.assert_array_equal(
+                    np.asarray(got), np.asarray(dense[strip][:, r, :, :n]),
+                    err_msg=f"{name} row {r}")

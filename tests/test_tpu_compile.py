@@ -4,6 +4,7 @@ cannot see (unaligned tiles, VMEM overuse, programs that do not fit), and
 reports what a program holds.  Nothing runs; shapes only.  The topology is described inside a fixture, so importing
 this file never loads the TPU library."""
 import dataclasses
+import functools
 import os
 import re
 
@@ -60,17 +61,21 @@ def _assert_kernel(compiled):
 
 
 def test_paged_decode_kernel_compiles(one_chip):
-    pool = _shape((N_BLOCKS, HKV, BLOCK, HD), jnp.bfloat16, one_chip)
+    pool = _shape((CFG.n_layers, N_BLOCKS, HKV, BLOCK, HD), jnp.bfloat16,
+                  one_chip)
     _assert_kernel(paged_decode_attention_pallas.lower(
         _shape((BATCH, HQ, 1, HD), jnp.bfloat16, one_chip), pool, pool,
+        _shape((), jnp.int32, one_chip),
         _shape((BATCH, MAX_BLOCKS), jnp.int32, one_chip),
         _shape((BATCH,), jnp.int32, one_chip)).compile())
 
 
 def test_paged_prefill_kernel_compiles(one_chip):
-    pool = _shape((N_BLOCKS, HKV, BLOCK, HD), jnp.bfloat16, one_chip)
+    pool = _shape((CFG.n_layers, N_BLOCKS, HKV, BLOCK, HD), jnp.bfloat16,
+                  one_chip)
     _assert_kernel(paged_prefill_attention_pallas.lower(
         _shape((1, HQ, BLOCK, HD), jnp.bfloat16, one_chip), pool, pool,
+        _shape((), jnp.int32, one_chip),
         _shape((1, MAX_BLOCKS), jnp.int32, one_chip),
         _shape((1,), jnp.int32, one_chip)).compile())
 
@@ -83,17 +88,81 @@ def test_flash_prefill_compiles_any_length(one_chip, s):
                                                 causal=True).compile())
 
 
-def test_decode_paged_step_compiles_at_full_width(one_chip):
+# the qwen3-0.6b.decode-batch cell's engine: 16 slots, 4,096 positions a
+# slot in blocks of 16, every slot's worst case reserved
+CELL_BATCH, CELL_CACHE_LEN = 16, 4096
+CELL_MAX_BLOCKS = blocks_needed(CELL_CACHE_LEN, BLOCK)
+CELL_N_BLOCKS = CELL_BATCH * CELL_MAX_BLOCKS + 1
+
+
+def _paged_step_compiled(one_chip, which):
+    """``model.decode_paged`` or ``model.prefill_paged`` at the cell's
+    shapes, the cache donated as the engine donates it, Pallas kernels."""
     model = build_model(CFG)
     params = _on(jax.eval_shape(model.init, jax.random.key(0)), one_chip)
     cache = _on(jax.eval_shape(lambda: model.paged_cache_init(
-        batch=BATCH, n_blocks=N_BLOCKS, block_size=BLOCK,
-        max_blocks=MAX_BLOCKS, dtype=jnp.bfloat16)), one_chip)
-    toks = _shape((BATCH, 1), jnp.int32, one_chip)
+        batch=CELL_BATCH, n_blocks=CELL_N_BLOCKS, block_size=BLOCK,
+        max_blocks=CELL_MAX_BLOCKS, dtype=jnp.bfloat16)), one_chip)
+    i32 = functools.partial(_shape, dtype=jnp.int32, sharding=one_chip)
+    if which == "decode":
+        args = (params, cache, i32((CELL_BATCH, 1)))
+    else:
+        args = (params, cache, {"tokens": i32((1, BLOCK))}, i32(()),
+                i32(()), i32(()))
+    fn = model.decode_paged if which == "decode" else model.prefill_paged
     with ops.impl_scope("pallas"):
-        compiled = jax.jit(model.decode_paged, donate_argnums=(1,)).lower(
-            params, cache, toks).compile()
+        return jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+
+
+_HLO_DEF = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+?)(?:\{[^}]*\})? "
+                      r"([\w-]+)\(([^)]*)", re.M)
+
+
+def _pool_sized_moves(hlo_text, layer_pool, stack):
+    """The instructions that materialise one layer's pool, or that copy
+    the stack or write more than a block of it."""
+    defs = {m.group(1): (m.group(2), m.group(3), m.group(4))
+            for m in _HLO_DEF.finditer(hlo_text)}
+    bad = []
+    for name, (shape, op, operands) in defs.items():
+        if shape == layer_pool:
+            bad.append((name, op))
+        elif shape == stack and ("copy" in op or name.startswith("copy")):
+            bad.append((name, op))
+        elif shape == stack and op == "dynamic-update-slice":
+            update = operands.split(",")[1].strip().lstrip("%")
+            if not re.fullmatch(r"bf16\[1,1,\d+,\d+,\d+\]",
+                                defs.get(update, ("?",))[0]):
+                bad.append((name, op))
+    return bad
+
+
+def _assert_reads_the_stack_in_place(compiled):
+    """No temporary reaches one layer's pool (134,250,496 B at the cell's
+    shapes), and no op materialises a layer's pool, copies the stack, or
+    writes more than one block into it: the kernels read their operand
+    straight from the stacked pool that the layer scan carries."""
     _assert_kernel(compiled)
+    layer_pool = (CELL_N_BLOCKS, HKV, BLOCK, HD)
+    layer_bytes = 2 * CELL_N_BLOCKS * HKV * BLOCK * HD
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    as_hlo = lambda dims: "bf16[" + ",".join(map(str, dims)) + "]"
+    assert _pool_sized_moves(compiled.as_text(), as_hlo(layer_pool),
+                             as_hlo((CFG.n_layers,) + layer_pool)) == []
+
+
+def test_decode_paged_step_compiles_at_full_width(one_chip):
+    """The decode step at the decode-batch cell's shapes (slicing each
+    layer's pool out and back took 538,564,096 B of temporaries)."""
+    _assert_reads_the_stack_in_place(_paged_step_compiled(one_chip,
+                                                          "decode"))
+
+
+def test_prefill_paged_chunk_compiles_at_full_width(one_chip):
+    """One prefill chunk at the decode-batch cell's shapes (slicing each
+    layer's pool out and back took 403,421,184 B of temporaries)."""
+    _assert_reads_the_stack_in_place(_paged_step_compiled(one_chip,
+                                                          "prefill"))
 
 
 def test_xlstm_decode_step_updates_state_in_place(one_chip):
